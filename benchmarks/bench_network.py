@@ -50,7 +50,7 @@ AB_T_STEPS = 60
 AB_T_STEPS_SMOKE = 24
 
 
-def _make_net(layers, seed=0):
+def make_net(layers, seed=0):
     rng = np.random.default_rng(seed)
     ws = []
     for i in range(len(layers) - 1):
@@ -60,7 +60,7 @@ def _make_net(layers, seed=0):
     return ws, params
 
 
-def _poisson_spikes(t, b, n, rate=0.25, seed=1):
+def poisson_spikes(t, b, n, rate=0.25, seed=1):
     rng = np.random.default_rng(seed)
     return (rng.random((t, b, n)) < rate).astype(np.float32) * 1.5
 
@@ -165,8 +165,8 @@ def run_fused_ab(full: bool = False, smoke: bool = False) -> dict:
     from repro.core.network import NetworkEngine, snn_spec
 
     t_steps = AB_T_STEPS_SMOKE if smoke else AB_T_STEPS
-    ws, params = _make_net(AB_LAYERS, seed=5)
-    spikes = _poisson_spikes(t_steps, BATCH, AB_LAYERS[0], seed=6)
+    ws, params = make_net(AB_LAYERS, seed=5)
+    spikes = poisson_spikes(t_steps, BATCH, AB_LAYERS[0], seed=6)
     fams = ("mean", "linear", "mlp")
     sur = surrogate("lif", full, families=fams)
     spec = snn_spec(ws, params)
@@ -293,8 +293,8 @@ def run(full: bool = False):
         return out
 
     layers = SNN_LAYERS_FULL if full else SNN_LAYERS
-    ws, params = _make_net(layers)
-    spikes = _poisson_spikes(T_STEPS, BATCH, layers[0])
+    ws, params = make_net(layers)
+    spikes = poisson_spikes(T_STEPS, BATCH, layers[0])
     fams = ("mean", "linear", "mlp")
     b = bank("lif", full, families=fams)
     sur = surrogate("lif", full, families=fams)

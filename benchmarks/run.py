@@ -105,6 +105,8 @@ def main() -> None:
                          "(summary + per-suite outputs) to PATH")
     args = ap.parse_args()
 
+    from repro.kernels import ops
+    ops.enable_compile_cache()
     from benchmarks import (bench_accuracy, bench_dse, bench_mixed,
                             bench_models, bench_network, bench_propagation,
                             bench_roofline, bench_scaling, bench_serve,
@@ -146,7 +148,6 @@ def main() -> None:
     if args.json:
         import jax
 
-        from repro.kernels import ops
         payload = {
             "schema": 1,
             "generated_by": "benchmarks.run",

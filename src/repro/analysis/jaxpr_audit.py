@@ -524,7 +524,6 @@ def pinned_env():
     reproducible regardless of the caller's environment (the megakernel
     entrypoint opts in explicitly via ``fused_kernel=True``)."""
     pins = {"REPRO_FUSED_KERNEL": "0", "REPRO_TICK_PALLAS": "0",
-            "REPRO_PALLAS_INTERPRET": "1",
             # fault injection must never perturb traced programs or
             # their budgets ("" reads as no plan via fault_plan_path)
             "REPRO_FAULT_PLAN": ""}

@@ -23,7 +23,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -58,13 +57,13 @@ def shard_over_batch(fn, mesh: Mesh, in_specs, out_specs,
     streaming path donates its chunk-to-chunk carries (and the surrogate
     leaves) so XLA aliases them in place instead of copying per chunk.
 
-    ``check_rep=False``: jax 0.4 has no replication rule for
-    ``pallas_call``, so the static replication checker rejects any body
-    that launches a kernel (e.g. the tick megakernel under
+    ``check_vma=False``: the varying-manual-axes checker has no rule for
+    ``pallas_call``, so it rejects any body that launches a kernel (the
+    tick megakernel compiled on an accelerator, or forced on the CPU with
     ``REPRO_TICK_PALLAS=1``); disabling the check changes no numerics —
     the per-shard body and its collectives run identically."""
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False),
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False),
                    donate_argnums=donate_argnums)
 
 
@@ -94,10 +93,10 @@ def _sharded_step(mesh: Mesh, surrogate_template, *, clock_ns: float,
                              tuple(mesh.axis_names))
         return new_state, e_tot, n_out
 
-    sm = shard_map(body, mesh=mesh,
-                   in_specs=(sur_spec, state_spec, cspec, cspec, P()),
-                   out_specs=(state_spec, P(), P()),
-                   check_rep=False)     # pallas_call has no replication rule
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(sur_spec, state_spec, cspec, cspec, P()),
+                       out_specs=(state_spec, P(), P()),
+                       check_vma=False)  # pallas_call has no vma rule
     return jax.jit(sm)
 
 

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.surrogate import F32_DOT
+
 
 # --- standardization -----------------------------------------------------------
 
@@ -329,7 +331,9 @@ class MLPModel(SurrogateModel):
     def _apply(params, x):
         h = x
         for i, lyr in enumerate(params):
-            h = h @ lyr["w"] + lyr["b"]
+            # f32 on every backend, so a surrogate trained on the chip
+            # matches one trained on a CPU
+            h = jnp.matmul(h, lyr["w"], precision=F32_DOT) + lyr["b"]
             if i < len(params) - 1:
                 h = jax.nn.relu(h)
         return h[..., 0]

@@ -51,6 +51,13 @@ from repro.core.circuits import get_circuit
 
 FORMAT_VERSION = 1
 
+# Surrogate inference contracts in f32. On a TPU an f32 matmul at the
+# default precision rounds its operands to bf16, so the chip's records
+# would drift from the CPU's and the inference paths (per-call, stacked,
+# megakernel) from each other. Every inference dot asks for HIGHEST; on
+# the CPU that changes nothing.
+F32_DOT = jax.lax.Precision.HIGHEST
+
 
 # --- static manifest ------------------------------------------------------------
 
@@ -123,13 +130,14 @@ def _predict_mean(a, x):
 
 def _predict_linear(a, x):
     xs = (x - a["mu"]) / a["sd"]
-    return xs @ a["w"][:-1] + a["w"][-1]
+    return jnp.matmul(xs, a["w"][:-1], precision=F32_DOT) + a["w"][-1]
 
 
 def _predict_table(a, x):
     xs = (x - a["mu"]) / a["sd"]
     tx = a["tx"]
-    d = jnp.sum(jnp.square(tx), -1)[None, :] - 2.0 * (xs @ tx.T)
+    d = jnp.sum(jnp.square(tx), -1)[None, :] \
+        - 2.0 * jnp.matmul(xs, tx.T, precision=F32_DOT)
     return a["ty"][jnp.argmin(d, axis=1)]
 
 
@@ -152,7 +160,7 @@ def _predict_mlp(a, x):
     h = (x - a["x_mu"]) / a["x_sd"]
     n_layers = sum(1 for k in a if k.startswith("w"))
     for i in range(n_layers):
-        h = h @ a[f"w{i}"] + a[f"b{i}"]
+        h = jnp.matmul(h, a[f"w{i}"], precision=F32_DOT) + a[f"b{i}"]
         if i < n_layers - 1:
             h = jax.nn.relu(h)
     return h[..., 0] * a["y_sd"][0] + a["y_mu"][0]
@@ -192,14 +200,15 @@ def _predict_mean_stacked(heads, x):
 def _predict_linear_stacked(heads, x):
     s = _stack_arrays(heads)
     xs = (x[None] - s["mu"][:, None]) / s["sd"][:, None]
-    return jnp.einsum("pnf,pf->pn", xs, s["w"][:, :-1]) + s["w"][:, -1:]
+    return jnp.einsum("pnf,pf->pn", xs, s["w"][:, :-1],
+                      precision=F32_DOT) + s["w"][:, -1:]
 
 
 def _predict_table_stacked(heads, x):
     s = _stack_arrays(heads)
     xs = (x[None] - s["mu"][:, None]) / s["sd"][:, None]
     d = jnp.sum(jnp.square(s["tx"]), -1)[:, None, :] \
-        - 2.0 * jnp.einsum("pnf,prf->pnr", xs, s["tx"])
+        - 2.0 * jnp.einsum("pnf,prf->pnr", xs, s["tx"], precision=F32_DOT)
     return jnp.take_along_axis(s["ty"], jnp.argmin(d, axis=2), axis=1)
 
 
@@ -215,7 +224,8 @@ def _predict_mlp_stacked(heads, x, fused_kernel=None):
             s["w0"], s["b0"], s["w1"], s["b1"], s["w2"], s["b2"])
     h = (x[None] - s["x_mu"][:, None]) / s["x_sd"][:, None]
     for i in range(n_layers):
-        h = jnp.einsum("pnf,pfh->pnh", h, s[f"w{i}"]) + s[f"b{i}"][:, None]
+        h = jnp.einsum("pnf,pfh->pnh", h, s[f"w{i}"],
+                       precision=F32_DOT) + s[f"b{i}"][:, None]
         if i < n_layers - 1:
             h = jax.nn.relu(h)
     return h[..., 0] * s["y_sd"][:, :1] + s["y_mu"][:, :1]

@@ -33,7 +33,7 @@ def _make_kernel(n_inputs, g_unit, r_f, v_sat, v_bias, tau_base):
 
 
 def crossbar_target(v, w, *, g_unit=12e-6, r_f=40e3, v_sat=2.0, v_bias=0.8,
-                    tau_base=0.15, block_n: int = 256, interpret: bool = True):
+                    tau_base=0.15, block_n: int = 1024, interpret: bool = True):
     """v: (N, n_in), w: (N, n_in+1) -> (v_tgt (N,), tau (N,))."""
     n, n_in = v.shape
     assert n % block_n == 0, (n, block_n)

@@ -19,18 +19,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.surrogate import F32_DOT
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=F32_DOT)
+
 
 def _mlp_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    h1 = jnp.maximum(
-        jnp.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
-        + b1_ref[...], 0.0)
-    h2 = jnp.maximum(
-        jnp.dot(h1, w2_ref[...], preferred_element_type=jnp.float32)
-        + b2_ref[...], 0.0)
-    out = jnp.dot(h2, w3_ref[...], preferred_element_type=jnp.float32) \
-        + b3_ref[...]
-    o_ref[...] = out
+    h1 = jnp.maximum(_dot(x, w1_ref[...]) + b1_ref[...], 0.0)
+    h2 = jnp.maximum(_dot(h1, w2_ref[...]) + b2_ref[...], 0.0)
+    o_ref[...] = _dot(h2, w3_ref[...]) + b3_ref[...]
 
 
 def mlp_surrogate(x, w1, b1, w2, b2, w3, b3, *, block_n: int = 256,
@@ -75,14 +76,9 @@ def _mlp_heads_kernel(x_ref, xmu_ref, xsd_ref, ymu_ref, ysd_ref,
     p = w1_ref.shape[0]
     for i in range(p):
         xs = (x - xmu_ref[i]) / xsd_ref[i]
-        h1 = jnp.maximum(
-            jnp.dot(xs, w1_ref[i], preferred_element_type=jnp.float32)
-            + b1_ref[i], 0.0)
-        h2 = jnp.maximum(
-            jnp.dot(h1, w2_ref[i], preferred_element_type=jnp.float32)
-            + b2_ref[i], 0.0)
-        out = jnp.dot(h2, w3_ref[i], preferred_element_type=jnp.float32) \
-            + b3_ref[i]
+        h1 = jnp.maximum(_dot(xs, w1_ref[i]) + b1_ref[i], 0.0)
+        h2 = jnp.maximum(_dot(h1, w2_ref[i]) + b2_ref[i], 0.0)
+        out = _dot(h2, w3_ref[i]) + b3_ref[i]
         o_ref[i] = out * ysd_ref[i] + ymu_ref[i]
 
 

@@ -1,13 +1,18 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; interpret mode
-executes kernel bodies in Python for correctness validation). On real TPUs
-set ``REPRO_PALLAS_INTERPRET=0`` / pass interpret=False and the same
-BlockSpecs compile to Mosaic.
+The platform chooses how a kernel runs: under JAX's CPU backend every
+``pallas_call`` runs in interpret mode (kernel bodies execute as jnp ops,
+for correctness validation); on an accelerator the same BlockSpecs
+compile to Mosaic. ``interpret=`` overrides it per call.
 
 Wrappers handle padding to hardware-aligned shapes so callers stay
 shape-agnostic: MLP feature dims pad to 128, circuit counts pad to the
-block size.
+block size (1024 for kernels with per-circuit 1-D blocks, the tiling XLA
+gives a 1-D f32 array on TPU).
+
+This is also the one module that reads the process environment
+(``REPRO_*`` knobs, the compile-cache location); the program auditor's
+env-discipline pass checks that no other module does.
 """
 
 from __future__ import annotations
@@ -25,8 +30,30 @@ from repro.kernels import lif_scan as _lif
 from repro.kernels import mlp_surrogate as _mlp
 
 
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
 def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+    """Pallas runs interpreted iff JAX's default backend is the CPU."""
+    return jax.default_backend() == "cpu"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+    ``python -m repro.serve``) call this once at start-up. When
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
+    is set here; otherwise the cache lives at ``<checkout>/.jax_cache`` —
+    a fixed path, so a later process finds what an earlier one compiled.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def fused_kernel_enabled(override: bool | None = None) -> bool:
@@ -49,10 +76,10 @@ def tick_pallas_enabled(override: bool | None = None) -> bool:
 
     Resolution order: explicit ``override`` kwarg, then the
     ``REPRO_TICK_PALLAS`` env var ("1"/"0"), then the platform default —
-    Pallas on real accelerators, the mathematically identical jnp body on
-    CPU (where interpret-mode Pallas adds per-tick overhead for no gain).
-    CI sets ``REPRO_TICK_PALLAS=1`` to execute the kernel code path in
-    interpret mode on the CPU container.
+    compiled Pallas on an accelerator, the mathematically identical jnp
+    body on the CPU (where interpret-mode Pallas adds per-tick overhead
+    for no gain). CI sets ``REPRO_TICK_PALLAS=1`` to execute the kernel
+    code path in interpret mode on the CPU.
     """
     if override is not None:
         return bool(override)
@@ -242,7 +269,7 @@ def mlp_surrogate_heads(x, x_mu, x_sd, y_mu, y_sd, w1, b1, w2, b2, w3, b3,
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def crossbar_target(v, w, *, block_n: int = 256, interpret: bool | None = None):
+def crossbar_target(v, w, *, block_n: int = 1024, interpret: bool | None = None):
     """(N, n_in), (N, n_in+1) -> (v_tgt (N,), tau (N,))."""
     interpret = _interpret_default() if interpret is None else interpret
     n = v.shape[0]
@@ -254,7 +281,7 @@ def crossbar_target(v, w, *, block_n: int = 256, interpret: bool | None = None):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def lif_step(state, x, params, *, block_n: int = 256,
+def lif_step(state, x, params, *, block_n: int = 1024,
              interpret: bool | None = None):
     """One golden LIF clock period for N neurons (kernelized SPICE farm)."""
     interpret = _interpret_default() if interpret is None else interpret
@@ -267,13 +294,14 @@ def lif_step(state, x, params, *, block_n: int = 256,
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def lif_chunk(state, x_seq, params, *, block_n: int = 256,
+def lif_chunk(state, x_seq, params, *, block_n: int = 1024,
               interpret: bool | None = None):
     """T golden LIF clock periods as ONE time-looped kernel launch.
 
     ``x_seq`` is (T, N, 3); circuit state stays VMEM-resident across the
     whole chunk (the lif_scan substep loop nests inside an outer tick
-    loop). Per-tick observables come back as (T, N) sequences.
+    loop, which walks the chunk in VMEM-sized time blocks). Per-tick
+    observables come back as (T, N) sequences.
     """
     interpret = _interpret_default() if interpret is None else interpret
     n = state.shape[0]

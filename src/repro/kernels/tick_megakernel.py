@@ -46,11 +46,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.circuits import augment_features, get_circuit
+from repro.core.surrogate import F32_DOT
 from repro.core.wrapper import (LasanaState, _features, _finish_tick,
                                 _resolve_output, _splice_transition)
 from repro.kernels import ops
+from repro.kernels.lif_scan import block_ticks, time_blocks
 
 # Stack membership, in stack order. The A stack serves BOTH the idle and
 # the active variant (same feature width); the T stack serves the
@@ -271,15 +274,17 @@ def _eval_stack(s, x, off: int, fams):
             y = jnp.broadcast_to(s["b2"][i, 0], (n,))
         elif fam == "linear":
             xs = (x - s["x_mu"][i]) / s["x_sd"][i]
-            y = jnp.dot(xs, s["w0"][i, :, 0],
-                        preferred_element_type=f32) + s["b2"][i, 0]
+            # (F, 1) column, not an (F,) vector: Mosaic has no rule for a
+            # matrix-vector product's 1-D accumulator
+            y = jnp.dot(xs, s["w0"][i, :, :1], precision=F32_DOT,
+                        preferred_element_type=f32)[:, 0] + s["b2"][i, 0]
         else:
             xs = (x - s["x_mu"][i]) / s["x_sd"][i]
-            h = jax.nn.relu(jnp.dot(xs, s["w0"][i],
+            h = jax.nn.relu(jnp.dot(xs, s["w0"][i], precision=F32_DOT,
                                     preferred_element_type=f32) + s["b0"][i])
-            h = jax.nn.relu(jnp.dot(h, s["w1"][i],
+            h = jax.nn.relu(jnp.dot(h, s["w1"][i], precision=F32_DOT,
                                     preferred_element_type=f32) + s["b1"][i])
-            y = jnp.dot(h, s["w2"][i],
+            y = jnp.dot(h, s["w2"][i], precision=F32_DOT,
                         preferred_element_type=f32)[:, 0] + s["b2"][i, 0]
         ys.append((y * s["y_sd"][i, 0] + s["y_mu"][i, 0]) / s["scale"][i, 0])
     return ys
@@ -449,7 +454,7 @@ def _resident(arr):
     """BlockSpec pinning a whole array into every grid step (VMEM-resident
     weights/standardizers, exactly like mlp_surrogate's head stacks)."""
     nd = arr.ndim
-    return pl.BlockSpec(arr.shape, lambda i, _nd=nd: (0,) * _nd)
+    return pl.BlockSpec(arr.shape, lambda *_, _nd=nd: (0,) * _nd)
 
 
 def _stack_refs(refs, base):
@@ -472,7 +477,7 @@ def _make_tick_kernel(circuit, clock_ns, out_eps, spiking, vdd, annotate,
         params = refs[i + 3][...]
         changed = refs[i + 4][...] > 0.5
         x = refs[i + 5][...]
-        t = refs[i + 6][0]
+        t = refs[i + 6][0]                  # SMEM scalar
         known = refs[i + 7][...] if annotate else None
         v_ref, o_ref, tl_ref, e_ref, l_ref = refs[i + 8:i + 13]
         v1, o1, tl1, e1, l1 = _tick_arrays(
@@ -509,7 +514,7 @@ def network_tick(pack, v, o, t_last, params, changed, x, t, known, *,
                  circuit, clock_ns, layout: PackLayout,
                  out_eps: float = 0.02, spiking: bool = False,
                  vdd: float = 1.5, annotate: bool = False,
-                 block_n: int = 256, interpret: bool | None = None):
+                 block_n: int = 1024, interpret: bool | None = None):
     """One whole LASANA tick as ONE ``pallas_call``.
 
     Ragged shapes are handled HERE (the raw kernel is shape-strict): N
@@ -542,7 +547,7 @@ def network_tick(pack, v, o, t_last, params, changed, x, t, known, *,
         pl.BlockSpec((block_n, params.shape[1]), lambda i: (i, 0)),
         n_blk,
         pl.BlockSpec((block_n, x.shape[1]), lambda i: (i, 0)),
-        pl.BlockSpec((1,), lambda i: (0,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         n_blk,
     ]
     kernel = _make_tick_kernel(circuit, clock_ns, out_eps, spiking, vdd,
@@ -558,40 +563,51 @@ def network_tick(pack, v, o, t_last, params, changed, x, t, known, *,
     return tuple(a[:n] for a in out)
 
 
-def _make_chunk_kernel(circuit, clock_ns, out_eps, spiking, vdd, layout):
+def _make_chunk_kernel(circuit, clock_ns, out_eps, spiking, vdd, layout,
+                       t_steps, tb, t_pad):
     """Time-looped kernel body: circuit state (v, o, t_last) lives in
     VMEM across the whole chunk; per-tick inputs are sliced and per-tick
     outputs stored inside the ``fori_loop`` (lif_scan's structure, one
-    level up the stack)."""
+    level up the stack). The grid's second axis walks time blocks; the
+    state output blocks stay resident across it and carry the state from
+    one time block to the next."""
 
     def kernel(*refs):
         sA = _stack_refs(refs, 0)
         sT = _stack_refs(refs, _N_STACK)
         i = 2 * _N_STACK
-        v0, o0, tl0 = refs[i][...], refs[i + 1][...], refs[i + 2][...]
         params = refs[i + 3][...]
         ch_ref, x_ref, t_ref = refs[i + 4], refs[i + 5], refs[i + 6]
         v_ref, o_ref, tl_ref = refs[i + 7], refs[i + 8], refs[i + 9]
         os_ref, es_ref, ls_ref = refs[i + 10], refs[i + 11], refs[i + 12]
-        t_steps = ch_ref.shape[0]
-        row = (slice(None),)
+        n_in = x_ref.shape[0]
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            v_ref[...] = refs[i][...]
+            o_ref[...] = refs[i + 1][...]
+            tl_ref[...] = refs[i + 2][...]
 
         def body(ti, carry):
             v, o, tl = carry
-            ch = pl.load(ch_ref, (pl.dslice(ti, 1), *row))[0] > 0.5
-            xx = pl.load(x_ref, (pl.dslice(ti, 1), *row, slice(None)))[0]
-            t = pl.load(t_ref, (pl.dslice(ti, 1), *row))[0, 0]
+            row = pl.ds(ti, 1)
+            ch = ch_ref[row, :][0] > 0.5
+            xx = jnp.stack([x_ref[c, row, :][0] for c in range(n_in)],
+                           axis=-1)
+            t = t_ref[0, 0, ti]             # SMEM scalar
             v1, o1, tl1, e1, l1 = _tick_arrays(
                 sA, sT, v, o, tl, params, ch, xx, t, circuit=circuit,
                 clock_ns=clock_ns, out_eps=out_eps, spiking=spiking,
                 vdd=vdd, annotate=False, known_out=None, layout=layout,
                 skip=False)
-            pl.store(os_ref, (pl.dslice(ti, 1), *row), o1[None])
-            pl.store(es_ref, (pl.dslice(ti, 1), *row), e1[None])
-            pl.store(ls_ref, (pl.dslice(ti, 1), *row), l1[None])
+            os_ref[row, :] = o1[None]
+            es_ref[row, :] = e1[None]
+            ls_ref[row, :] = l1[None]
             return v1, o1, tl1
 
-        v, o, tl = jax.lax.fori_loop(0, t_steps, body, (v0, o0, tl0))
+        v, o, tl = jax.lax.fori_loop(
+            0, block_ticks(t_steps, tb, t_pad), body,
+            (v_ref[...], o_ref[...], tl_ref[...]))
         v_ref[...] = v
         o_ref[...] = o
         tl_ref[...] = tl
@@ -604,20 +620,26 @@ def _make_chunk_kernel(circuit, clock_ns, out_eps, spiking, vdd, layout):
 def network_tick_chunk(pack, v, o, t_last, params, changed_seq, x_seq,
                        t_seq, *, circuit, clock_ns, layout: PackLayout,
                        out_eps: float = 0.02, spiking: bool = True,
-                       vdd: float = 1.5, block_n: int = 256,
+                       vdd: float = 1.5, block_n: int = 1024,
                        interpret: bool | None = None):
     """A whole chunk of LASANA ticks as ONE time-looped ``pallas_call``.
 
     ``changed_seq`` (T, N) bool, ``x_seq`` (T, N, n_in), ``t_seq`` (T,)
     tick times. Circuit state never leaves VMEM between ticks; only the
-    per-tick record sequences stream out. Returns
+    per-tick record sequences stream out. The grid walks circuit blocks
+    and, inside each, time blocks (``lif_scan.time_blocks``), so VMEM use
+    is bounded for any chunk length; stimulus columns are laid out
+    ``(n_in, T, N)`` so every per-tick row is lane-dense. Returns
     ``(v', o', t_last', o_seq, e_seq, l_seq)``."""
     interpret = ops._interpret_default() if interpret is None else interpret
     n = v.shape[0]
     t_steps = changed_seq.shape[0]
     n_pad = ops._ceil_to(n, block_n)
+    tb, t_pad = time_blocks(t_steps)
     pp = _padded_pack(pack)
     f32 = jnp.float32
+    pad_nt = lambda a: jnp.pad(                       # noqa: E731
+        a, [(0, 0)] * (a.ndim - 2) + [(0, t_pad - t_steps), (0, n_pad - n)])
     inputs = (
         *[pp["a"][k] for k in _STACK_KEYS],
         *[pp["t"][k] for k in _STACK_KEYS],
@@ -625,32 +647,35 @@ def network_tick_chunk(pack, v, o, t_last, params, changed_seq, x_seq,
         ops._pad_to(o, n_pad, 0),
         ops._pad_to(t_last, n_pad, 0),
         ops._pad_to(params, n_pad, 0),
-        ops._pad_to(changed_seq.astype(f32), n_pad, 1),
-        ops._pad_to(x_seq, n_pad, 1),
-        jnp.reshape(jnp.asarray(t_seq, f32), (t_steps, 1)),
+        pad_nt(changed_seq.astype(f32)),
+        pad_nt(jnp.moveaxis(x_seq, 2, 0)),
+        ops._pad_to(jnp.asarray(t_seq, f32).reshape(t_steps), t_pad, 0
+                    ).reshape(t_pad // tb, 1, tb),
     )
-    n_blk = pl.BlockSpec((block_n,), lambda i: (i,))
-    seq_blk = pl.BlockSpec((t_steps, block_n), lambda i: (0, i))
+    n_blk = pl.BlockSpec((block_n,), lambda i, j: (i,))
+    seq_blk = pl.BlockSpec((tb, block_n), lambda i, j: (j, i))
     in_specs = [
         *[_resident(a) for a in inputs[:2 * _N_STACK]],
         n_blk, n_blk, n_blk,
-        pl.BlockSpec((block_n, params.shape[1]), lambda i: (i, 0)),
+        pl.BlockSpec((block_n, params.shape[1]), lambda i, j: (i, 0)),
         seq_blk,
-        pl.BlockSpec((t_steps, block_n, x_seq.shape[2]),
-                     lambda i: (0, i, 0)),
-        pl.BlockSpec((t_steps, 1), lambda i: (0, 0)),
+        pl.BlockSpec((x_seq.shape[2], tb, block_n), lambda i, j: (0, j, i)),
+        pl.BlockSpec((1, 1, tb), lambda i, j: (j, 0, 0),
+                     memory_space=pltpu.SMEM),
     ]
     kernel = _make_chunk_kernel(circuit, clock_ns, out_eps, spiking, vdd,
-                                layout)
+                                layout, t_steps, tb, t_pad)
     out = pl.pallas_call(
         kernel,
-        grid=(n_pad // block_n,),
+        grid=(n_pad // block_n, t_pad // tb),
         in_specs=in_specs,
         out_specs=[n_blk] * 3 + [seq_blk] * 3,
         out_shape=[jax.ShapeDtypeStruct((n_pad,), f32)] * 3
-        + [jax.ShapeDtypeStruct((t_steps, n_pad), f32)] * 3,
+        + [jax.ShapeDtypeStruct((t_pad, n_pad), f32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)
     v1, o1, tl1, o_seq, e_seq, l_seq = out
-    return (v1[:n], o1[:n], tl1[:n],
-            o_seq[:, :n], e_seq[:, :n], l_seq[:, :n])
+    return (v1[:n], o1[:n], tl1[:n], o_seq[:t_steps, :n],
+            e_seq[:t_steps, :n], l_seq[:t_steps, :n])

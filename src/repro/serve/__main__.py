@@ -79,6 +79,8 @@ def main():
                     help="TCP port (default: stdin/stdout)")
     ap.add_argument("--host", default="127.0.0.1")
     args = ap.parse_args()
+    from repro.kernels import ops
+    ops.enable_compile_cache()
     serve(args)
 
 

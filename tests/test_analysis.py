@@ -86,7 +86,7 @@ def test_fp64_promotion_caught():
     def promoting(x):
         return x.astype(jnp.float64) * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         entry = TracedEntry(fn=promoting,
                             args=(jnp.zeros((3,), jnp.float32),))
         _, findings = jaxpr_audit.audit_entry("wide_tick", entry)

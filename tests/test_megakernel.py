@@ -123,7 +123,7 @@ def _assert_parity(runs):
 # --- the property sweep -----------------------------------------------------
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(min_value=0, max_value=10**6),
        st.sampled_from(["l", "ll", "xl", "lx", "xll"]),
        st.booleans())
@@ -137,7 +137,7 @@ def test_random_specs_three_way_parity(lif_bank, crossbar_bank, seed, topo,
                               _libraries(lif_bank, crossbar_bank, topo)))
 
 
-@settings(max_examples=3)
+@settings(max_examples=3, deadline=None)
 @given(st.integers(min_value=0, max_value=10**6),
        st.sampled_from(["ll", "xl"]))
 def test_random_specs_annotation_parity(lif_bank, crossbar_bank, seed,
@@ -261,7 +261,8 @@ def test_fused_kernel_enabled_env_vs_kwarg(monkeypatch):
         assert ops.fused_kernel_enabled(True) is True
         assert ops.fused_kernel_enabled(False) is False
 
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret_default() is True
     for env, expect in ((None, False), ("0", False), ("1", True)):
         if env is None:
             monkeypatch.delenv("REPRO_TICK_PALLAS", raising=False)
@@ -270,10 +271,36 @@ def test_fused_kernel_enabled_env_vs_kwarg(monkeypatch):
         assert ops.tick_pallas_enabled() is expect
         assert ops.tick_pallas_enabled(True) is True
         assert ops.tick_pallas_enabled(False) is False
-    # platform default: hardware (interpret off) runs the Pallas launcher
+    # platform default: an accelerator runs the compiled Pallas launcher
     monkeypatch.delenv("REPRO_TICK_PALLAS", raising=False)
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret_default() is False
     assert ops.tick_pallas_enabled() is True
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_enable_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; without it the
+    cache sits at a fixed path in the checkout, never a per-process one."""
+    import os
+    from repro.kernels import ops
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(ops._REPO_ROOT, ".jax_cache")
+            assert ops.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.isfile(os.path.join(ops._REPO_ROOT, "src", "repro",
+                                               "kernels", "ops.py"))
+        else:
+            path = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+            assert ops.enable_compile_cache() == path
+            assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_surrogate_predict_heads_kwarg_matches_env(lif_bank, monkeypatch):
