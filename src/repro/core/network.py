@@ -88,6 +88,7 @@ Pass ``mesh=Mesh(...)`` to shard the batch axis.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 import warnings
@@ -111,6 +112,12 @@ CIRCUIT_KINDS = ("lif", "crossbar")
 # a crossbar row-segment has an input event iff any of its sample-and-hold
 # input lines carries a live (nonzero) voltage this tick
 _XBAR_EVENT_EPS = 1e-6
+
+
+def _span(name: str, **stats):
+    """The host span ``lasana.<name>`` with ``stats`` as its counters, on
+    the profiler's clock; inert unless a ``jax.profiler`` session is on."""
+    return jax.profiler.TraceAnnotation("lasana." + name, **stats)
 
 
 # --- network specification ----------------------------------------------------
@@ -708,6 +715,8 @@ class NetworkEngine:
         self._compile_lock = threading.Lock()
         self.compile_count = 0        # distinct compiled network programs
         self._trace_count = 0         # times a sim body was (re)traced
+        self._calls = itertools.count()   # ``call`` stat of the run spans
+        self._call = threading.local()    # the thread's call, for ``_run``
 
     def _normalize_surrogates(self, src) -> SurrogateLibrary:
         """Coerce surrogates/bank input into a validated SurrogateLibrary."""
@@ -765,14 +774,24 @@ class NetworkEngine:
         ``surrogates`` overrides the engine-bound library for THIS run only:
         because surrogates are traced arguments of the compiled program,
         swapping a retrained library with identical manifests/shapes reuses
-        the cached executable (zero recompiles)."""
-        x = jnp.asarray(inputs, jnp.float32)
-        if x.ndim == 2:
-            x = x[None]
-        if x.shape[-1] != self.spec.layers[0].fan_in:
-            raise ValueError(f"input width {x.shape[-1]} != layer-0 fan_in "
-                             f"{self.spec.layers[0].fan_in}")
-        return self._run(x, surrogates=surrogates)
+        the cached executable (zero recompiles).
+
+        Under ``jax.profiler`` the call is the host span ``lasana.run``,
+        tiled by ``lasana.stimulus``, ``lasana.prepare`` (holding
+        ``lasana.compile`` on a cache miss), ``lasana.execute`` and
+        ``lasana.fetch`` (docs/architecture.md, "Tracing")."""
+        call = self._call.n = next(self._calls)
+        with _span("run", call=call) as span:
+            with _span("stimulus", call=call) as stim:
+                x = jnp.asarray(inputs, jnp.float32)
+                stim.set_metadata(bytes=x.nbytes)
+            if x.ndim == 2:
+                x = x[None]
+            if x.shape[-1] != self.spec.layers[0].fan_in:
+                raise ValueError(f"input width {x.shape[-1]} != layer-0 "
+                                 f"fan_in {self.spec.layers[0].fan_in}")
+            span.set_metadata(ticks=x.shape[0], batch=x.shape[1])
+            return self._run(x, surrogates=surrogates)
 
     def run_stream(self, stimulus, *, chunk_ticks: Optional[int] = None,
                    surrogates=None) -> NetworkRun:
@@ -1151,8 +1170,9 @@ class NetworkEngine:
             # drive is (B_local, n_out): under shard_map the batch dim is
             # shard-local, so every shape below derives from the input
             t = (k + 1.0) * clock
-            xin = drive_to_circuit_inputs(drive, spike_amp=amp
-                                          ).reshape(-1, 3)
+            with jax.named_scope("drive"):
+                xin = drive_to_circuit_inputs(drive, spike_amp=amp
+                                              ).reshape(-1, 3)
 
             if backend == "golden":
                 state, params = carry
@@ -1188,15 +1208,17 @@ class NetworkEngine:
                                           fused_kernel=fused_kernel,
                                           megakernel_pack=pack,
                                           megakernel_layout=layout)
-                spikes = jnp.where(changed, o, 0.0)
+                with jax.named_scope("update"):
+                    spikes = jnp.where(changed, o, 0.0)
                 carry = ns
 
-            spikes = spikes.reshape(-1, n_out)
-            if slot_records:
-                ev = jnp.sum(changed.reshape(spikes.shape[0], -1),
-                             axis=1, dtype=jnp.int32)
-            else:
-                ev = _count_events(changed)
+            with jax.named_scope("update"):
+                spikes = spikes.reshape(-1, n_out)
+                if slot_records:
+                    ev = jnp.sum(changed.reshape(spikes.shape[0], -1),
+                                 axis=1, dtype=jnp.int32)
+                else:
+                    ev = _count_events(changed)
             return carry, spikes, e, l, ev
 
         return tick
@@ -1226,11 +1248,13 @@ class NetworkEngine:
             # params ride in the carry so they shard with the rows
             b_l = x.shape[0]
             t = (k + 1.0) * clock
-            xp = jnp.pad(x, ((0, 0), (0, n_seg * seg_w - fan_in)))
-            xin = xp.reshape(b_l, n_seg, seg_w)
-            xin = jnp.broadcast_to(xin[:, None], (b_l, n_out, n_seg, seg_w)
-                                   ).reshape(-1, seg_w)
-            changed = jnp.any(jnp.abs(xin) > _XBAR_EVENT_EPS, axis=-1)
+            with jax.named_scope("drive"):
+                xp = jnp.pad(x, ((0, 0), (0, n_seg * seg_w - fan_in)))
+                xin = xp.reshape(b_l, n_seg, seg_w)
+                xin = jnp.broadcast_to(xin[:, None],
+                                       (b_l, n_out, n_seg, seg_w)
+                                       ).reshape(-1, seg_w)
+                changed = jnp.any(jnp.abs(xin) > _XBAR_EVENT_EPS, axis=-1)
 
             if backend == "golden":
                 state, pall = carry
@@ -1264,15 +1288,17 @@ class NetworkEngine:
                 carry = ns
                 v = ns.o
 
-            # adc_bits ADC over [-v_sat, v_sat], then digital gain comp
-            v_adc = (jnp.round((v + circ.v_sat) / (2 * circ.v_sat) * levels)
-                     / levels * 2 * circ.v_sat - circ.v_sat)
-            y = v_adc.reshape(-1, n_out, n_seg).sum(-1) / gain
-            if slot_records:
-                ev = jnp.sum(changed.reshape(b_l, -1),
-                             axis=1, dtype=jnp.int32)
-            else:
-                ev = _count_events(changed)
+            with jax.named_scope("update"):
+                # adc_bits ADC over [-v_sat, v_sat], then digital gain comp
+                v_adc = (jnp.round((v + circ.v_sat) / (2 * circ.v_sat)
+                                   * levels)
+                         / levels * 2 * circ.v_sat - circ.v_sat)
+                y = v_adc.reshape(-1, n_out, n_seg).sum(-1) / gain
+                if slot_records:
+                    ev = jnp.sum(changed.reshape(b_l, -1),
+                                 axis=1, dtype=jnp.int32)
+                else:
+                    ev = _count_events(changed)
             return carry, y, e, l, ev
 
         return tick
@@ -1365,58 +1391,62 @@ class NetworkEngine:
                 layer = spec.layers[i]
                 pk, ly = packs.get(kinds[i], (None, None))
                 if kinds[i] == "lif":
-                    # combine feed-forward + delayed-edge synaptic drive
-                    u = adapt_signal(src_kind, "lif", cur, spike_amp=amp,
-                                     activation=src_activation(src_idx))
-                    drive = (u @ layer.weight) / amp
-                    pre = (jnp.abs(u) > event_threshold(src_kind, amp)
-                           ).astype(jnp.float32)
-                    incoming = (pre @ ff_conn[i]) > 0.5
-                    for src, we, conn in rec[i]:
-                        ur = adapt_signal(
-                            kinds[src], "lif", prev_ys[src],
-                            spike_amp=amp,
-                            activation=src_activation(src))
-                        drive = drive + (ur @ we) / amp
-                        pr = (jnp.abs(ur)
-                              > event_threshold(kinds[src], amp)
-                              ).astype(jnp.float32)
-                        incoming = incoming | ((pr @ conn) > 0.5)
-                    if live is not None:
-                        incoming = incoming & live[:, None]
-                    changed = incoming.reshape(-1)
+                    with jax.named_scope("drive"):
+                        # combine feed-forward + delayed-edge synaptic drive
+                        u = adapt_signal(src_kind, "lif", cur, spike_amp=amp,
+                                         activation=src_activation(src_idx))
+                        drive = (u @ layer.weight) / amp
+                        pre = (jnp.abs(u) > event_threshold(src_kind, amp)
+                               ).astype(jnp.float32)
+                        incoming = (pre @ ff_conn[i]) > 0.5
+                        for src, we, conn in rec[i]:
+                            ur = adapt_signal(
+                                kinds[src], "lif", prev_ys[src],
+                                spike_amp=amp,
+                                activation=src_activation(src))
+                            drive = drive + (ur @ we) / amp
+                            pr = (jnp.abs(ur)
+                                  > event_threshold(kinds[src], amp)
+                                  ).astype(jnp.float32)
+                            incoming = incoming | ((pr @ conn) > 0.5)
+                        if live is not None:
+                            incoming = incoming & live[:, None]
+                        changed = incoming.reshape(-1)
                     carry, y, e, l, ev = ticks[i](carries[i], drive,
                                                   changed, k,
                                                   banks.get(kinds[i]),
                                                   pk, ly)
                 else:
                     circ = self.circs[i]
-                    xv = adapt_signal(src_kind, "crossbar", cur,
-                                      spike_amp=amp,
-                                      activation=src_activation(src_idx))
-                    for src, we, _ in rec[i]:
-                        xv = xv + adapt_signal(
-                            kinds[src], "crossbar", prev_ys[src],
-                            spike_amp=amp,
-                            activation=src_activation(src)) @ we
-                    xv = jnp.clip(xv, circ.input_lo, circ.input_hi)
-                    if live is not None:
-                        xv = jnp.where(live[:, None], xv, 0.0)
+                    with jax.named_scope("drive"):
+                        xv = adapt_signal(src_kind, "crossbar", cur,
+                                          spike_amp=amp,
+                                          activation=src_activation(src_idx))
+                        for src, we, _ in rec[i]:
+                            xv = xv + adapt_signal(
+                                kinds[src], "crossbar", prev_ys[src],
+                                spike_amp=amp,
+                                activation=src_activation(src)) @ we
+                        xv = jnp.clip(xv, circ.input_lo, circ.input_hi)
+                        if live is not None:
+                            xv = jnp.where(live[:, None], xv, 0.0)
                     carry, y, e, l, ev = ticks[i](carries[i], xv, k,
                                                   banks.get(kinds[i]),
                                                   pk, ly)
                 new_carries.append(carry)
                 new_ys.append(y)
-                if slot_records:   # per-tenant attribution: reduce per slot
-                    es.append(jnp.sum(e.reshape(bsz, -1), axis=1))
-                    ls.append(jnp.max(l.reshape(bsz, -1), axis=1))
-                else:
-                    es.append(jnp.sum(e))
-                    ls.append(jnp.max(l))
+                with jax.named_scope("update"):
+                    if slot_records:   # per-tenant attribution: per slot
+                        es.append(jnp.sum(e.reshape(bsz, -1), axis=1))
+                        ls.append(jnp.max(l.reshape(bsz, -1), axis=1))
+                    else:
+                        es.append(jnp.sum(e))
+                        ls.append(jnp.max(l))
                 evs.append(ev)
                 cur, src_kind, src_idx = y, kinds[i], i
-            return (new_carries, new_ys, jnp.stack(es), jnp.stack(ls),
-                    jnp.stack(evs))
+            with jax.named_scope("update"):
+                records = jnp.stack(es), jnp.stack(ls), jnp.stack(evs)
+            return (new_carries, new_ys, *records)
 
         return cascade
 
@@ -1563,10 +1593,12 @@ class NetworkEngine:
                 primary = jnp.sum(out_seq > 0.5 * amp, axis=0)
             else:
                 primary = out_seq[-1]
-            flush = jnp.stack([
-                self._flush(carries[i], i, t_steps * self.circs[i].clock_ns,
-                            banks.get(kinds[i]))
-                for i in range(n_layers)])
+            with jax.named_scope("flush"):
+                flush = jnp.stack([
+                    self._flush(carries[i], i,
+                                t_steps * self.circs[i].clock_ns,
+                                banks.get(kinds[i]))
+                    for i in range(n_layers)])
             if sharded:        # diagnostics are the only collectives
                 e_tl = jax.lax.psum(e_tl, axes)
                 l_tl = jax.lax.pmax(l_tl, axes)
@@ -1656,9 +1688,10 @@ class NetworkEngine:
         sharded = self.mesh is not None
 
         def flush_fn(carries, t_ends, banks):
-            flush = jnp.stack([self._flush(carries[i], i, t_ends[i],
-                                           banks.get(kinds[i]))
-                               for i in range(n_layers)])
+            with jax.named_scope("flush"):
+                flush = jnp.stack([self._flush(carries[i], i, t_ends[i],
+                                               banks.get(kinds[i]))
+                                   for i in range(n_layers)])
             if sharded:
                 flush = jax.lax.psum(flush, tuple(self.mesh.axis_names))
             return flush
@@ -1743,6 +1776,7 @@ class NetworkEngine:
         kinds = spec.circuits
         n_layers = spec.n_layers
 
+        @jax.named_scope("flush")
         def flush_fn(carries, t_ends, banks):
             rows = []
             for i in range(n_layers):
@@ -1917,14 +1951,24 @@ class NetworkEngine:
             entry = self._sim_cache.get(key)
             if entry is not None:
                 return entry[0], 0.0
-            fn = build()
-            t0 = time.time()
-            compiled = fn.lower(*example_args).compile()
-            compile_s = time.time() - t0
+            with _span("compile", kind=key[0]):
+                fn = build()
+                t0 = time.time()
+                compiled = fn.lower(*example_args).compile()
+                compile_s = time.time() - t0
             self._sim_cache[key] = (compiled, compile_s)
             if key[0] in ("mono", "stream", "slot"):
                 self.compile_count += 1
         return compiled, compile_s
+
+    def compiled_hlo(self) -> list:
+        """The optimized HLO text of every program this engine has
+        compiled, oldest first; reading it changes nothing. A profiler
+        trace names each device operation as this text does, and its
+        ``metadata={op_name=...}`` carries the tick cascade's stage scopes
+        (docs/architecture.md, "Tracing")."""
+        return [compiled.as_text()
+                for compiled, _ in list(self._sim_cache.values())]
 
     def _check_mesh_batch(self, b: int):
         if self.mesh is not None:
@@ -1936,35 +1980,46 @@ class NetworkEngine:
 
     def _run(self, x, *, surrogates=None) -> NetworkRun:
         spec = self.spec
-        t_steps, b, _ = x.shape
-        self._check_mesh_batch(b)
-        banks = self._runtime_banks(surrogates)
-        carries = [self._init_carry(i, b) for i in range(spec.n_layers)]
-        prev0 = [jnp.zeros((b, l.n_out), jnp.float32) for l in spec.layers]
+        call = self._call.n
+        with _span("prepare", call=call):
+            t_steps, b, _ = x.shape
+            self._check_mesh_batch(b)
+            banks = self._runtime_banks(surrogates)
+            carries = [self._init_carry(i, b) for i in range(spec.n_layers)]
+            prev0 = [jnp.zeros((b, l.n_out), jnp.float32)
+                     for l in spec.layers]
+            # AOT-compile once per (shapes, surrogate structure): later
+            # runs — including runs with swapped surrogate weights — only
+            # execute
+            key = self._program_key("mono", b, t_steps, banks)
+            compiled, compile_s = self._compiled(
+                key, lambda: self._build_sim(b, banks),
+                (x, carries, prev0, banks))
+            if compile_s == 0.0:
+                compile_s = self._sim_cache[key][1]    # historical build time
 
-        # AOT-compile once per (shapes, surrogate structure): later runs
-        # — including runs with swapped surrogate weights — only execute
-        key = self._program_key("mono", b, t_steps, banks)
-        compiled, compile_s = self._compiled(
-            key, lambda: self._build_sim(b, banks),
-            (x, carries, prev0, banks))
-        if compile_s == 0.0:
-            compile_s = self._sim_cache[key][1]    # historical build time
-
-        t0 = time.time()
-        primary, out_seq, hidden, e_tl, l_tl, ev_tl, flush = \
-            jax.block_until_ready(compiled(x, carries, prev0, banks))
-        wall = time.time() - t0
+        with _span("execute", call=call):
+            t0 = time.perf_counter()
+            primary, out_seq, hidden, e_tl, l_tl, ev_tl, flush = \
+                jax.block_until_ready(compiled(x, carries, prev0, banks))
+            wall = time.perf_counter() - t0
         last_lif = spec.circuits[-1] == "lif"
-        return NetworkRun(
-            backend=self.backend, mode=self.mode,
-            outputs=np.asarray(primary),
-            out_spikes=np.asarray(out_seq) if last_lif else None,
-            layer_spikes=[np.asarray(h) for h in hidden]
-            if self.record_hidden else None,
-            energy=np.asarray(e_tl), latency=np.asarray(l_tl),
-            events=np.asarray(ev_tl, np.int64),
-            flush_energy=np.asarray(flush),
-            n_circuits=np.asarray([l.n_circuits(b) for l in spec.layers]),
-            clock_ns=self.clock_ns, wall_seconds=wall,
-            circuits=spec.circuits, compile_seconds=compile_s)
+        with _span("fetch", call=call) as span:
+            run = NetworkRun(
+                backend=self.backend, mode=self.mode,
+                outputs=np.asarray(primary),
+                out_spikes=np.asarray(out_seq) if last_lif else None,
+                layer_spikes=[np.asarray(h) for h in hidden]
+                if self.record_hidden else None,
+                energy=np.asarray(e_tl), latency=np.asarray(l_tl),
+                events=np.asarray(ev_tl, np.int64),
+                flush_energy=np.asarray(flush),
+                n_circuits=np.asarray([l.n_circuits(b)
+                                       for l in spec.layers]),
+                clock_ns=self.clock_ns, wall_seconds=wall,
+                circuits=spec.circuits, compile_seconds=compile_s)
+            fetched = [primary, e_tl, l_tl, ev_tl, flush, *hidden]
+            if last_lif:
+                fetched.append(out_seq)
+            span.set_metadata(bytes=sum(a.nbytes for a in fetched))
+        return run

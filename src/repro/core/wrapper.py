@@ -162,10 +162,11 @@ def lasana_step(surrogate, state: LasanaState, changed, x, t, clock_ns, *,
             if pack is None:
                 pack, layout = mk.pack_heads(surrogate)
             if pack is not None:
-                return mk.megakernel_step(
-                    pack, surrogate.manifest.circuit, state, changed, x, t,
-                    clock_ns, out_eps=out_eps, spiking=spiking,
-                    known_out=known_out, vdd=vdd, layout=layout)
+                with jax.named_scope("heads"):
+                    return mk.megakernel_step(
+                        pack, surrogate.manifest.circuit, state, changed, x,
+                        t, clock_ns, out_eps=out_eps, spiking=spiking,
+                        known_out=known_out, vdd=vdd, layout=layout)
         return _lasana_step_fused(surrogate, state, changed, x, t, clock_ns,
                                   out_eps=out_eps, spiking=spiking,
                                   known_out=known_out, vdd=vdd,
@@ -200,61 +201,68 @@ def _lasana_step_fused(surrogate, state, changed, x, t, clock_ns, *,
     circuit = surrogate.manifest.circuit
 
     # --- lines 3-9: catch up stale circuits with one merged idle event
-    stale = changed & (state.t_last < t - clock_ns)
-    tau_idle = jnp.maximum(t - state.t_last - clock_ns, 0.0)
-    feats_idle = _features(jnp.zeros_like(x), state.v, tau_idle,
-                           state.params)
-    tau_act = jnp.full((n,), clock_ns, jnp.float32)
+    with jax.named_scope("features"):
+        stale = changed & (state.t_last < t - clock_ns)
+        tau_idle = jnp.maximum(t - state.t_last - clock_ns, 0.0)
+        aug_idle = _augment(circuit, _features(jnp.zeros_like(x), state.v,
+                                               tau_idle, state.params))
+        tau_act = jnp.full((n,), clock_ns, jnp.float32)
 
     if annotate:
         v_cur = state.v            # behavioral state: never stale
         v_new = v_cur              # caller overwrites with behavioral state
         o_hat = known_out
-        feats = _features(x, v_cur, tau_act, state.params)
-        out_changed, o_resolved = _resolve_output(
-            o_hat, state.o, out_eps=out_eps, spiking=spiking, vdd=vdd)
-        aug_act = _augment(circuit, feats)
-        aug_tr = _splice_transition(aug_act, feats.shape[1], state.o,
-                                    o_resolved)
-        r = surrogate.predict_heads(
-            feats_idle=_augment(circuit, feats_idle), feats_act=aug_act,
-            feats_tr=aug_tr,
-            heads={"idle": ("M_ES",), "act": ("M_ES",),
-                   "tr": ("M_ED", "M_L")},
-            augmented=True, fused_kernel=fused_kernel)
+        with jax.named_scope("features"):
+            feats = _features(x, v_cur, tau_act, state.params)
+            out_changed, o_resolved = _resolve_output(
+                o_hat, state.o, out_eps=out_eps, spiking=spiking, vdd=vdd)
+            aug_act = _augment(circuit, feats)
+            aug_tr = _splice_transition(aug_act, feats.shape[1], state.o,
+                                        o_resolved)
+        with jax.named_scope("heads"):
+            r = surrogate.predict_heads(
+                feats_idle=aug_idle, feats_act=aug_act, feats_tr=aug_tr,
+                heads={"idle": ("M_ES",), "act": ("M_ES",),
+                       "tr": ("M_ED", "M_L")},
+                augmented=True, fused_kernel=fused_kernel)
         e_s_idle = r["idle"]["M_ES"]
         e_s, e_d, lat = r["act"]["M_ES"], r["tr"]["M_ED"], r["tr"]["M_L"]
     else:
-        r1 = surrogate.predict_heads(feats_idle=feats_idle,
-                                     heads={"idle": ("M_ES", "M_V")},
-                                     fused_kernel=fused_kernel)
+        with jax.named_scope("heads"):
+            r1 = surrogate.predict_heads(feats_idle=aug_idle,
+                                         heads={"idle": ("M_ES", "M_V")},
+                                         augmented=True,
+                                         fused_kernel=fused_kernel)
         e_s_idle = r1["idle"]["M_ES"]
-        v_cur = jnp.where(stale, r1["idle"]["M_V"], state.v)
 
         # --- lines 10-22: one stacked pass over the whole active variant
         # (M_O's prediction chains into the transition-aware heads, but
         # M_V/M_ES don't consume it — so they ride the same dispatch)
-        feats = _features(x, v_cur, tau_act, state.params)
-        aug_act = _augment(circuit, feats)
-        r2 = surrogate.predict_heads(feats_act=aug_act,
-                                     heads={"act": ("M_O", "M_V", "M_ES")},
-                                     augmented=True,
-                                     fused_kernel=fused_kernel)
+        with jax.named_scope("features"):
+            v_cur = jnp.where(stale, r1["idle"]["M_V"], state.v)
+            feats = _features(x, v_cur, tau_act, state.params)
+            aug_act = _augment(circuit, feats)
+        with jax.named_scope("heads"):
+            r2 = surrogate.predict_heads(
+                feats_act=aug_act, heads={"act": ("M_O", "M_V", "M_ES")},
+                augmented=True, fused_kernel=fused_kernel)
         o_hat, v_new, e_s = (r2["act"]["M_O"], r2["act"]["M_V"],
                              r2["act"]["M_ES"])
-        out_changed, o_resolved = _resolve_output(
-            o_hat, state.o, out_eps=out_eps, spiking=spiking, vdd=vdd)
-        aug_tr = _splice_transition(aug_act, feats.shape[1], state.o,
-                                    o_resolved)
-        r3 = surrogate.predict_heads(feats_tr=aug_tr,
-                                     heads={"tr": ("M_ED", "M_L")},
-                                     augmented=True,
-                                     fused_kernel=fused_kernel)
+        with jax.named_scope("features"):
+            out_changed, o_resolved = _resolve_output(
+                o_hat, state.o, out_eps=out_eps, spiking=spiking, vdd=vdd)
+            aug_tr = _splice_transition(aug_act, feats.shape[1], state.o,
+                                        o_resolved)
+        with jax.named_scope("heads"):
+            r3 = surrogate.predict_heads(
+                feats_tr=aug_tr, heads={"tr": ("M_ED", "M_L")},
+                augmented=True, fused_kernel=fused_kernel)
         e_d, lat = r3["tr"]["M_ED"], r3["tr"]["M_L"]
 
-    return _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,
-                        out_changed, o_hat, v_cur, v_new, t,
-                        spiking=spiking, vdd=vdd)
+    with jax.named_scope("update"):
+        return _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,
+                            out_changed, o_hat, v_cur, v_new, t,
+                            spiking=spiking, vdd=vdd)
 
 
 def _lasana_step_percall(surrogate, state, changed, x, t, clock_ns, *,
@@ -298,9 +306,10 @@ def _lasana_step_percall(surrogate, state, changed, x, t, clock_ns, *,
     e_d = surrogate.predict("M_ED", feats_tr)
     e_s = surrogate.predict("M_ES", feats)
     lat = surrogate.predict("M_L", feats_tr)
-    return _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,
-                        out_changed, o_hat, v_cur, v_new, t,
-                        spiking=spiking, vdd=vdd)
+    with jax.named_scope("update"):
+        return _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,
+                            out_changed, o_hat, v_cur, v_new, t,
+                            spiking=spiking, vdd=vdd)
 
 
 def _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,
