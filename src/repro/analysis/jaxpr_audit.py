@@ -3,12 +3,13 @@
 Every invariant the benchmarks enforce dynamically has a static shadow
 here, checked from the *traced program* before anything compiles or runs:
 
-  * **dispatch budgets** — ``Surrogate.predict`` / ``predict_heads`` and
-    the whole-tick megakernel report each surrogate dispatch through
-    ``ops.record_dispatch`` at trace time; scan bodies trace once, so the
-    per-trace count is the per-tick dispatch count. Architectural
-    ceilings (fused <= 3, annotation/megakernel == 1, per-call == 7) are
-    hard-coded per entrypoint and cannot be regenerated away.
+  * **dispatch budgets** — ``Surrogate.predict`` / ``predict_heads`` /
+    ``predict_blocks`` and the whole-tick megakernel report each
+    surrogate dispatch through ``ops.record_dispatch`` at trace time;
+    scan bodies trace once, so the per-trace count is the per-tick
+    dispatch count. Architectural ceilings (fused and blocks <= 3,
+    annotation/megakernel == 1, per-call == 7) are hard-coded per
+    entrypoint and cannot be regenerated away.
   * **dot/scan/pallas counts** — a recursive jaxpr walk (descending into
     ``pjit``/``scan``/``cond`` sub-jaxprs) frozen per entrypoint in
     ``tests/data/program_budgets.json`` (the ``check_api.py`` pattern:
@@ -316,6 +317,21 @@ def _entry_tick_xbar(ctx: AuditContext) -> TracedEntry:
                                    fused_kernel=False)
     return TracedEntry(fn=fn, args=(ctx.xbar, state, changed, x, t),
                        max_dispatch={"predict_heads": 3, "predict": 0})
+
+
+@ops.register_entrypoint("tick_xbar_blocks")
+def _entry_tick_xbar_blocks(ctx: AuditContext) -> TracedEntry:
+    """A crossbar layer's network tick, heads evaluated by column blocks
+    (no per-row feature matrix): three ``predict_blocks`` dispatches."""
+    from repro.core.network import NetworkEngine, crossbar_mlp_spec
+    w = np.linspace(-1.0, 1.0, 40 * 3, dtype=np.float32).reshape(40, 3)
+    eng = NetworkEngine(crossbar_mlp_spec([np.sign(w)]), backend="lasana")
+    x = jnp.zeros((ctx.b, 40), jnp.float32)
+    return TracedEntry(fn=eng._xbar_tick(0),
+                       args=(eng._init_carry(0, ctx.b), x,
+                             jnp.float32(2.0), ctx.xbar),
+                       max_dispatch={"predict_blocks": 3,
+                                     "predict_heads": 0, "predict": 0})
 
 
 @ops.register_entrypoint("explore_pricing")

@@ -102,7 +102,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.circuits import CrossbarRow, LIFNeuron, get_circuit
 from repro.core.distributed import batch_spec, shard_over_batch
 from repro.core.surrogate import Surrogate, SurrogateLibrary, as_surrogate
-from repro.core.wrapper import LasanaState, init_state, lasana_step
+from repro.core.wrapper import (LasanaState, RowBlocks, init_state,
+                                lasana_step, row_blocks_ok)
 
 P_REPL = P()                     # replicated diagnostics spec
 BACKENDS = ("golden", "behavioral", "lasana")
@@ -1241,52 +1242,72 @@ class NetworkEngine:
         backend, mode = self.backend, self.mode
         fused = self.fused
         fused_kernel = self.fused_kernel
+        # row parameters per (output, segment): every lane's rows share
+        # them (the carry holds them tiled per lane)
+        rows = _row_segments(layer.weight, seg_w).reshape(n_out, n_seg, -1)
+
+        def by_blocks(bank, pack) -> bool:
+            from repro.kernels import ops
+            return (backend == "lasana" and mode == "standalone" and fused
+                    and pack is None
+                    and not ops.fused_kernel_enabled(fused_kernel)
+                    and row_blocks_ok(bank))
 
         def tick(carry, x, k, bank, pack=None, layout=None):
             # x is (B_local, fan_in) volts: under shard_map the batch dim is
-            # shard-local, so every shape below derives from the input; row
-            # params ride in the carry so they shard with the rows
+            # shard-local, so every shape below derives from the input; the
+            # per-row path's row params ride in the carry so they shard
+            # with the rows
             b_l = x.shape[0]
             t = (k + 1.0) * clock
             with jax.named_scope("drive"):
                 xp = jnp.pad(x, ((0, 0), (0, n_seg * seg_w - fan_in)))
-                xin = xp.reshape(b_l, n_seg, seg_w)
-                xin = jnp.broadcast_to(xin[:, None],
-                                       (b_l, n_out, n_seg, seg_w)
-                                       ).reshape(-1, seg_w)
-                changed = jnp.any(jnp.abs(xin) > _XBAR_EVENT_EPS, axis=-1)
+                x_seg = xp.reshape(b_l, n_seg, seg_w)
+                live = jnp.any(jnp.abs(x_seg) > _XBAR_EVENT_EPS, axis=-1)
+                changed = jnp.broadcast_to(live[:, None],
+                                           (b_l, n_out, n_seg)).reshape(-1)
 
-            if backend == "golden":
-                state, pall = carry
-                v_prev = state[:, 0]
-                _, obs = circ.step(state, xin, pall)
-                v = jnp.where(changed, obs["output"], v_prev)
-                e = jnp.where(changed, obs["energy"], 0.0)
-                l = jnp.where(changed, obs["latency"], 0.0)
-                carry = (v[:, None], pall)
-            elif backend == "behavioral":
-                held, pall = carry
-                _, settled = circ.behavioral_step(held, xin, pall)
-                v = jnp.where(changed, settled, held)
-                e = jnp.zeros_like(v)
-                l = jnp.zeros_like(v)
-                carry = (v, pall)
+            if by_blocks(bank, pack):
+                rb = RowBlocks(x=x_seg[:, None],
+                               params=jnp.asarray(rows)[None])
+                ns, e, l, _ = lasana_step(bank, carry, changed, rb, t, clock)
+                carry, v = ns, ns.o
             else:
-                known = None
-                if mode == "annotation":
-                    _, known = circ.behavioral_step(carry.v, xin,
-                                                    carry.params)
-                ns, e, l, _ = lasana_step(bank, carry, changed, xin, t,
-                                          clock, known_out=known,
-                                          fused=fused,
-                                          fused_kernel=fused_kernel,
-                                          megakernel_pack=pack,
-                                          megakernel_layout=layout)
-                if known is not None:
-                    # behavioral value is both published output and state
-                    ns = ns._replace(v=ns.o)
-                carry = ns
-                v = ns.o
+                with jax.named_scope("drive"):
+                    xin = jnp.broadcast_to(x_seg[:, None],
+                                           (b_l, n_out, n_seg, seg_w)
+                                           ).reshape(-1, seg_w)
+                if backend == "golden":
+                    state, pall = carry
+                    v_prev = state[:, 0]
+                    _, obs = circ.step(state, xin, pall)
+                    v = jnp.where(changed, obs["output"], v_prev)
+                    e = jnp.where(changed, obs["energy"], 0.0)
+                    l = jnp.where(changed, obs["latency"], 0.0)
+                    carry = (v[:, None], pall)
+                elif backend == "behavioral":
+                    held, pall = carry
+                    _, settled = circ.behavioral_step(held, xin, pall)
+                    v = jnp.where(changed, settled, held)
+                    e = jnp.zeros_like(v)
+                    l = jnp.zeros_like(v)
+                    carry = (v, pall)
+                else:
+                    known = None
+                    if mode == "annotation":
+                        _, known = circ.behavioral_step(carry.v, xin,
+                                                        carry.params)
+                    ns, e, l, _ = lasana_step(bank, carry, changed, xin, t,
+                                              clock, known_out=known,
+                                              fused=fused,
+                                              fused_kernel=fused_kernel,
+                                              megakernel_pack=pack,
+                                              megakernel_layout=layout)
+                    if known is not None:
+                        # behavioral value is both published output and state
+                        ns = ns._replace(v=ns.o)
+                    carry = ns
+                    v = ns.o
 
             with jax.named_scope("update"):
                 # adc_bits ADC over [-v_sat, v_sat], then digital gain comp

@@ -129,7 +129,10 @@ def _predict_mean(a, x):
 
 
 def _predict_linear(a, x):
-    xs = (x - a["mu"]) / a["sd"]
+    return _linear_standardized(a, (x - a["mu"]) / a["sd"])
+
+
+def _linear_standardized(a, xs):
     return jnp.matmul(xs, a["w"][:-1], precision=F32_DOT) + a["w"][-1]
 
 
@@ -157,7 +160,10 @@ def _predict_gbdt(a, x):
 
 
 def _predict_mlp(a, x):
-    h = (x - a["x_mu"]) / a["x_sd"]
+    return _mlp_standardized(a, (x - a["x_mu"]) / a["x_sd"])
+
+
+def _mlp_standardized(a, h):
     n_layers = sum(1 for k in a if k.startswith("w"))
     for i in range(n_layers):
         h = jnp.matmul(h, a[f"w{i}"], precision=F32_DOT) + a[f"b{i}"]
@@ -263,6 +269,50 @@ ALG1_HEADS = {
     "act": ("M_O", "M_V", "M_ES"),
     "tr": ("M_ED", "M_L"),
 }
+
+
+# --- rows by column blocks -----------------------------------------------------
+#
+# Where feature rows repeat blocks of columns (crossbar rows: one input
+# segment per lane and segment, one weight segment per output and segment),
+# Surrogate.predict_blocks takes the blocks at their own, smaller, shapes.
+# Each head standardizes every block there and places it at its columns;
+# the sum of the placed blocks is the standardized row, an elementwise
+# expression that feeds the head's first dot alone, so the compiler builds
+# it inside the dot and no (N, F) matrix is written. Every element and every
+# dot is the arithmetic of predict on the concatenated rows.
+
+# the standardizer and the head on standardized rows, per family whose rows
+# may come in blocks (table/gbdt read raw rows; mean reads none)
+_STANDARDIZED = {"linear": ("mu", "sd", _linear_standardized),
+                 "mlp": ("x_mu", "x_sd", _mlp_standardized)}
+
+
+def _at_columns(z, at: int, width: int):
+    """Block ``z`` (..., w) zero-padded to a row of ``width`` columns,
+    placed at column ``at``; a sum of placed blocks is exactly the
+    concatenated row (every element is its block's value plus zeros).
+    A one-column block is placed by a product with a one-hot row, so
+    that the sum stays elementwise and the compiler builds it inside the
+    head's first dot."""
+    if z.shape[-1] == 1:
+        return z * (jnp.arange(width) == at)
+    return jnp.pad(z, [(0, 0)] * (z.ndim - 1)
+                   + [(at, width - at - z.shape[-1])])
+
+
+def _column_blocks(names) -> tuple:
+    """Feature names -> ((block, width), ...): consecutive columns whose
+    names agree up to a trailing index form one block ("x0".."x31" ->
+    ("x", 32))."""
+    blocks = []
+    for name in names:
+        base = name.rstrip("0123456789") or name
+        if blocks and blocks[-1][0] == base:
+            blocks[-1][1] += 1
+        else:
+            blocks.append([base, 1])
+    return tuple((b, w) for b, w in blocks)
 
 
 def _model_arrays(model) -> tuple:
@@ -490,6 +540,70 @@ class Surrogate:
                     ys = fn([self.params[p] for p in pnames], x)
                 for i, p in enumerate(pnames):
                     out[v][p] = ys[i] / self.manifest.scale_of(p)
+        return out
+
+    def column_blocks(self, pname: str, extra=()) -> Optional[tuple]:
+        """``((block, width), ...)``: the feature columns head ``pname``
+        reads, split into blocks, or None where its rows cannot come in
+        blocks (``table``, ``gbdt``, or a width too small for the names).
+
+        The blocks are the manifest's raw features grouped by name (``x``,
+        ``v``, ``tau``, ``p``), then one column per name in ``extra`` (a
+        transition head's ``o_prev``, ``o_new``), then the circuit's
+        derived columns as ``derived``: the order ``wrapper._features``
+        and the augmentation build. A ``mean`` head reads none: ``()``."""
+        fam = self.manifest.family_of(pname)
+        if fam == "mean":
+            return ()
+        if fam not in _STANDARDIZED:
+            return None
+        width = self.params[pname][_STANDARDIZED[fam][0]].shape[0]
+        blocks = (_column_blocks(self.manifest.features)
+                  + tuple((e, 1) for e in extra))
+        rest = width - sum(w for _, w in blocks)
+        if rest < 0:
+            return None
+        return blocks + ((("derived", rest),) if rest else ())
+
+    def predict_blocks(self, heads, blocks: dict, *, extra=()) -> dict:
+        """Heads on feature rows given as column blocks: ``{pname:
+        predictions}`` in physical units, shaped as the rows.
+
+        ``blocks`` maps each block of :meth:`column_blocks` to an array
+        ``(..., width)``; the leading axes of all blocks broadcast to the
+        rows' shape. Each head standardizes every block at the block's
+        own shape, places it at its columns and sums the placed blocks to
+        its standardized rows, then runs as :meth:`predict`. The rows feed
+        the head's first dot alone, so the compiler builds them inside it
+        and writes no (N, F) matrix; every element and every dot is the
+        arithmetic of :meth:`predict` on the concatenated (augmented)
+        rows. ``mean``, ``linear`` and ``mlp`` heads only. One dispatch
+        for all ``heads``."""
+        from repro.kernels import ops
+        ops.record_dispatch("predict_blocks")
+        shape = jnp.broadcast_shapes(*(jnp.shape(c)[:-1]
+                                       for c in blocks.values()))
+        out = {}
+        for p in heads:
+            fam, a = self.manifest.family_of(p), self.params[p]
+            cols = self.column_blocks(p, extra)
+            if cols is None:
+                raise ValueError(f"predict_blocks: head {p!r} ({fam}) does "
+                                 "not take its rows in blocks")
+            if fam == "mean":
+                y = jnp.broadcast_to(
+                    jnp.asarray(a["mu"], jnp.float32).reshape(()), shape)
+            else:
+                mu_key, sd_key, head = _STANDARDIZED[fam]
+                mu, sd = a[mu_key], a[sd_key]
+                rows, at = None, 0
+                for name, width in cols:
+                    z = _at_columns((blocks[name] - mu[at:at + width])
+                                    / sd[at:at + width], at, mu.shape[0])
+                    rows = z if rows is None else rows + z
+                    at += width
+                y = head(a, jnp.broadcast_to(rows, shape + mu.shape))
+            out[p] = y / self.manifest.scale_of(p)
         return out
 
     def predict_np(self, pname: str, feats) -> np.ndarray:

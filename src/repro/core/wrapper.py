@@ -32,6 +32,10 @@ Public API
     ``fused=False`` keeps the original one-``predict``-per-head
     formulation (the benchmark A/B baseline; results agree within a few
     ULPs — see docs/architecture.md, "Inference hot path").
+:class:`RowBlocks` / :func:`row_blocks_ok`
+    rows that repeat blocks of columns (crossbar rows), handed to
+    ``lasana_step`` in place of per-row inputs, at the blocks' own
+    shapes: no (N, F) feature matrix is written
 :func:`lasana_step_reference`
     literal per-circuit numpy transcription, the parity oracle for tests
 
@@ -47,6 +51,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class LasanaState(NamedTuple):
@@ -56,6 +61,56 @@ class LasanaState(NamedTuple):
     o: jax.Array          # latest output
     t_last: jax.Array     # latest update time t'
     params: jax.Array     # (N, n_p) fixed circuit parameters
+
+
+# the columns a transition head reads after the active variant's raw ones
+_TR_COLUMNS = ("o_prev", "o_new")
+
+
+class RowBlocks(NamedTuple):
+    """Circuit rows as column blocks (``Surrogate.predict_blocks``): each
+    array's leading axes broadcast to the rows' grid, whose flattening is
+    the order of the state's N circuits."""
+
+    x: jax.Array             # (..., n_inputs) inputs applied at t
+    params: jax.Array        # (..., n_params) fixed circuit parameters
+
+
+def row_blocks_ok(surrogate) -> bool:
+    """Whether ``lasana_step`` can take ``surrogate``'s rows as
+    :class:`RowBlocks`: every Algorithm-1 head is ``mean``, ``linear`` or
+    ``mlp`` and reads its circuit's x, v, tau and params columns, a
+    transition head's o_prev and o_new, then the derived columns."""
+    from repro.core.circuits import get_circuit
+    from repro.core.surrogate import ALG1_HEADS
+    column_blocks = getattr(surrogate, "column_blocks", None)
+    heads = set(ALG1_HEADS["act"] + ALG1_HEADS["tr"])
+    if column_blocks is None or not heads <= set(
+            surrogate.manifest.predictors):
+        return False
+    try:
+        circ = get_circuit(surrogate.manifest.circuit)
+    except KeyError:
+        return False
+    base = (("x", circ.n_inputs), ("v", 1), ("tau", 1),
+            ("p", circ.n_params))
+    derived = _derived(circ, np.zeros((1, circ.n_inputs), np.float32),
+                       np.zeros((1, circ.n_params), np.float32))
+    for p in heads:
+        extra = _TR_COLUMNS if p in ALG1_HEADS["tr"] else ()
+        want = (base + tuple((c, 1) for c in extra)
+                + tuple(("derived", a.shape[-1]) for a in derived.values()))
+        if column_blocks(p, extra) not in ((), want):
+            return False
+    return True
+
+
+def _derived(circ, x, params) -> dict:
+    """The circuit's derived columns as a ``derived`` block (none for a
+    circuit without ``surrogate_features``), from blocks that broadcast:
+    the function the augmentation applies to whole rows."""
+    fn = getattr(circ, "surrogate_features", None)
+    return {} if fn is None else {"derived": fn(x, params)}
 
 
 def init_state(n: int, params) -> LasanaState:
@@ -113,7 +168,14 @@ def lasana_step(surrogate, state: LasanaState, changed, x, t, clock_ns, *,
              on the per-call path.
     state    LasanaState
     changed  (N,) bool — set S as a mask
-    x        (N, n_in) inputs applied at t (rows of X)
+    x        (N, n_in) inputs applied at t (rows of X), or a
+             :class:`RowBlocks`: the rows' inputs and parameters as column
+             blocks at their own, smaller, shapes, for rows that repeat
+             blocks (crossbar rows share input and weight segments). The
+             fused schedule then builds each head's rows from the blocks
+             inside the head's first dot and writes no (N, F) feature
+             matrix (``_lasana_step_blocks``). Standalone mode, for a
+             surrogate :func:`row_blocks_ok` accepts; the caller checks.
     t        scalar time (ns)
     known_out  (N,) optional — annotation mode: the output this tick is
              supplied by an external behavioral model, so M_O/M_V are
@@ -154,6 +216,10 @@ def lasana_step(surrogate, state: LasanaState, changed, x, t, clock_ns, *,
              None, the pack is derived from ``surrogate`` on the fly.
     returns  (new_state, e (N,), l (N,), o (N,))
     """
+    if isinstance(x, RowBlocks):
+        return _lasana_step_blocks(surrogate, state, changed, x, t,
+                                   clock_ns, out_eps=out_eps,
+                                   spiking=spiking, vdd=vdd)
     if fused and hasattr(surrogate, "predict_heads"):
         from repro.kernels import ops
         if ops.fused_kernel_enabled(fused_kernel):
@@ -258,6 +324,66 @@ def _lasana_step_fused(surrogate, state, changed, x, t, clock_ns, *,
                 feats_tr=aug_tr, heads={"tr": ("M_ED", "M_L")},
                 augmented=True, fused_kernel=fused_kernel)
         e_d, lat = r3["tr"]["M_ED"], r3["tr"]["M_L"]
+
+    with jax.named_scope("update"):
+        return _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,
+                            out_changed, o_hat, v_cur, v_new, t,
+                            spiking=spiking, vdd=vdd)
+
+
+def _lasana_step_blocks(surrogate, state, changed, blocks, t, clock_ns, *,
+                        out_eps, spiking, vdd):
+    """Algorithm 1 on rows given as :class:`RowBlocks`: the fused
+    path's schedule (idle M_ES + M_V -> active M_O + M_V + M_ES ->
+    transition M_ED + M_L), each stage one ``predict_blocks`` dispatch.
+
+    v, tau, o_prev and o_new are one-column blocks per row; inputs,
+    parameters and derived columns keep the blocks' shapes. Every head
+    computes what ``predict`` computes on the concatenated rows, so the
+    records are the fused path's wherever head stacking is exact, and
+    within its rtol 1e-5 elsewhere (docs/architecture.md, "Inference hot
+    path")."""
+    from repro.core.circuits import get_circuit
+    from repro.core.surrogate import ALG1_HEADS
+    circ = get_circuit(surrogate.manifest.circuit)
+    grid = jnp.broadcast_shapes(blocks.x.shape[:-1],
+                                blocks.params.shape[:-1])
+    zero_x = jnp.zeros((1,) * len(grid) + blocks.x.shape[-1:], jnp.float32)
+
+    def col(a):                       # one value per row, as a block
+        return a.reshape(*grid, 1)
+
+    def run(variant, rows, extra=()):
+        heads = ALG1_HEADS[variant]
+        r = surrogate.predict_blocks(heads, rows, extra=extra)
+        return tuple(r[p].reshape(-1) for p in heads)
+
+    # --- lines 3-9: catch up stale circuits with one merged idle event
+    with jax.named_scope("features"):
+        stale = changed & (state.t_last < t - clock_ns)
+        tau_idle = jnp.maximum(t - state.t_last - clock_ns, 0.0)
+        idle = {"x": zero_x, "v": col(state.v), "tau": col(tau_idle),
+                "p": blocks.params,
+                **_derived(circ, zero_x, blocks.params)}
+    with jax.named_scope("heads"):
+        e_s_idle, v_hat = run("idle", idle)
+
+    # --- lines 10-22: the active rows, then the transition rows
+    with jax.named_scope("features"):
+        v_cur = jnp.where(stale, v_hat, state.v)
+        act = {"x": blocks.x, "v": col(v_cur),
+               "tau": jnp.full(zero_x.shape[:-1] + (1,), clock_ns,
+                               jnp.float32),
+               "p": blocks.params,
+               **_derived(circ, blocks.x, blocks.params)}
+    with jax.named_scope("heads"):
+        o_hat, v_new, e_s = run("act", act)
+    with jax.named_scope("features"):
+        out_changed, o_resolved = _resolve_output(
+            o_hat, state.o, out_eps=out_eps, spiking=spiking, vdd=vdd)
+        tr = dict(act, o_prev=col(state.o), o_new=col(o_resolved))
+    with jax.named_scope("heads"):
+        e_d, lat = run("tr", tr, _TR_COLUMNS)
 
     with jax.named_scope("update"):
         return _finish_tick(state, changed, stale, e_s_idle, e_d, e_s, lat,

@@ -99,3 +99,92 @@ def test_sharded_equals_single_device(tmp_path):
     out = r.stdout + r.stderr
     assert r.returncode == 0, out[-3000:]
     assert "SHARDMAP-OK" in out
+
+
+_XBAR_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from jax.sharding import Mesh
+    from repro.core.circuits import augment_features, get_circuit
+    from repro.core.network import (NetworkEngine, crossbar_layer,
+                                    graph_spec, lif_layer, recurrent_edge)
+    from repro.core.surrogate import (FORMAT_VERSION, Manifest, Surrogate,
+                                      _feature_names)
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(11)
+
+    def surrogate(kind, families):
+        circ = get_circuit(kind)
+        f_raw = circ.n_inputs + 2 + circ.n_params
+        f = int(augment_features(circ, np.zeros((1, f_raw))).shape[1])
+        params = {}
+        for p, fam in families.items():
+            w = f + 2 if p in ("M_ED", "M_L") else f
+            if fam == "linear":
+                a = {"w": rng.normal(0, 0.3, w + 1), "mu": np.zeros(w),
+                     "sd": np.ones(w)}
+            else:
+                dims = (w, 16, 8, 1)
+                a = {f"w{i}": rng.normal(0, 1 / np.sqrt(dims[i]),
+                                         (dims[i], dims[i + 1]))
+                     for i in range(3)}
+                a.update({f"b{i}": rng.normal(0, 0.1, dims[i + 1])
+                          for i in range(3)})
+                a.update(x_mu=np.zeros(w), x_sd=np.ones(w),
+                         y_mu=np.asarray([0.1]), y_sd=np.asarray([0.8]))
+            params[p] = {k: jnp.asarray(v, jnp.float32)
+                         for k, v in a.items()}
+        return Surrogate(Manifest(
+            kind, FORMAT_VERSION, tuple(sorted(families.items())),
+            tuple((p, 1.0) for p in sorted(families)),
+            _feature_names(kind)), params)
+
+    fams = {"M_O": "mlp", "M_V": "mlp", "M_ED": "mlp", "M_ES": "linear",
+            "M_L": "linear"}
+    lib = {"crossbar": surrogate("crossbar", fams),
+           "lif": surrogate("lif", fams)}
+    xw = rng.integers(-1, 2, (40, 6)).astype(np.float32)
+    lw = rng.normal(0, 1.0, (6, 5)).astype(np.float32)
+    inhib = -0.6 * (1 - np.eye(5, dtype=np.float32))
+    spec = graph_spec([crossbar_layer(xw),
+                       lif_layer(lw, np.asarray([0.58, 0.5, 0.5, 0.5],
+                                                np.float32))],
+                      edges=[recurrent_edge(1, 1, inhib)])
+    x = (rng.integers(-1, 2, (12, 8, 40)) * 0.8).astype(np.float32)
+    x[3, 2:5] = 0.0
+    mesh = Mesh(np.array(jax.devices()[:4]), ("batch",))
+    base = NetworkEngine(spec, surrogates=lib).run(x)
+    sharded = NetworkEngine(spec, surrogates=lib, mesh=mesh)
+    with ops.dispatch_scope() as log:
+        shard = sharded.run(x)
+    assert log.count("predict_blocks") == 3, log
+    stream = sharded.run_stream(x, chunk_ticks=5)
+    for run in (shard, stream):
+        np.testing.assert_array_equal(base.events, run.events)
+        np.testing.assert_array_equal(base.outputs, run.outputs)
+        for a, b in zip(base.layer_spikes, run.layer_spikes):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(base.energy, run.energy, rtol=1e-6)
+        np.testing.assert_allclose(base.latency, run.latency, rtol=1e-6)
+    assert base.events[:, 0].sum() > 0 and base.events[:, 1].sum() > 0
+    print("XBAR-SHARDMAP-OK")
+""")
+
+
+def test_crossbar_network_sharded_equals_single_device(tmp_path):
+    """A crossbar -> LIF graph (recurrent edge) over a 4-device batch
+    mesh, monolithic and streamed, against one device: the crossbar
+    layer's block tick runs shard-local."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env.pop("XLA_FLAGS", None)
+    script = tmp_path / "xbar_shard_check.py"
+    script.write_text(_XBAR_SCRIPT)
+    r = subprocess.run([sys.executable, str(script)], capture_output=True,
+                       text=True, env=env, cwd=_ROOT, timeout=600)
+    out = r.stdout + r.stderr
+    assert r.returncode == 0, out[-3000:]
+    assert "XBAR-SHARDMAP-OK" in out

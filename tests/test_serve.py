@@ -194,6 +194,33 @@ def test_mixed_recurrent_graph_parity(lif_surrogate, crossbar_dataset):
         _assert_request_parity(solo, h.result())
 
 
+def test_mixed_graph_slots_run_crossbar_blocks(lif_surrogate,
+                                              crossbar_dataset):
+    """The acceptance graph's crossbar layer takes the block tick in the
+    slot program too (the parity above is then block tick against block
+    tick), while its LIF layer keeps the per-row fused path."""
+    from repro.core.predictors import PredictorBank
+    from repro.kernels import ops
+    rng = np.random.default_rng(3)
+    xw = rng.integers(-1, 2, (20, 8)).astype(np.float32)
+    lw = (rng.normal(0, 0.5, (8, 6)) * 2.2).astype(np.float32)
+    spec = graph_spec([crossbar_layer(xw),
+                       lif_layer(lw, jnp.asarray(PARAMS, jnp.float32))])
+    banks = {"lif": lif_surrogate,
+             "crossbar": PredictorBank("crossbar",
+                                       families=("mean", "linear")
+                                       ).fit(crossbar_dataset)}
+    x = (rng.integers(-1, 2, (9, 2, 20)) * 0.8).astype(np.float32)
+    srv = SimServer(ServeConfig(slot_widths=(4,), chunk_ticks=CHUNK))
+    with ops.dispatch_scope() as log:
+        h = srv.submit(spec, x, surrogates=banks)
+        srv.run_until_idle()
+    assert log.count("predict_blocks") >= 3
+    assert log.count("predict_blocks") == log.count("predict_heads")
+    _assert_request_parity(lasana.simulate(spec, x, surrogates=banks,
+                                           record_hidden=False), h.result())
+
+
 def test_annotation_mode_parity(lif_surrogate, shared_spec):
     rng = np.random.default_rng(4)
     x = _stim(rng, 13, 2)

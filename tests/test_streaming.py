@@ -132,6 +132,30 @@ def test_stream_mixed_recurrent_graph(lif_surrogate, small_net, mixed_net,
         _assert_runs_identical(mono, st)
 
 
+def test_stream_mixed_graph_runs_crossbar_blocks(lif_surrogate, mixed_net,
+                                                crossbar_dataset):
+    """The mixed graph's crossbar layer takes the block tick in the chunk
+    programs as in the monolithic one (so the bit-identity above holds
+    block tick against block tick); its LIF layer keeps the per-row
+    fused path."""
+    from repro.core.predictors import PredictorBank
+    from repro.kernels import ops
+    spec, seq = mixed_net
+    banks = {"lif": lif_surrogate,
+             "crossbar": PredictorBank("crossbar", families=("mean",
+                                                             "linear")
+                                       ).fit(crossbar_dataset)}
+    eng = NetworkEngine(spec, backend="lasana", surrogates=banks)
+    with ops.dispatch_scope() as mono:
+        ref = eng.run(seq)
+    with ops.dispatch_scope() as chunks:
+        st = eng.run_stream(seq, chunk_ticks=9)        # 9, 9 and 6 ticks
+    assert mono.count("predict_blocks") == mono.count("predict_heads") == 3
+    assert chunks.count("predict_blocks") == 6         # two chunk programs
+    assert chunks.count("predict_heads") == 6
+    _assert_runs_identical(ref, st)
+
+
 def test_stream_annotation_mode(lif_surrogate, small_net):
     spec, spikes = small_net
     eng = NetworkEngine(spec, backend="lasana", surrogates=lif_surrogate,
