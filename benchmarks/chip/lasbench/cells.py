@@ -6,7 +6,9 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
 
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 HARNESS_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -61,23 +63,33 @@ def resolve(root: str, workload: str) -> Cell:
         harness_dir=harness_dir)
 
 
-def metric_reader(harness_dir: str, name: str):
-    """``read(ctx) -> float | None`` of ``metrics/<name>.py``."""
-    path = os.path.join(harness_dir, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "lasbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+def load_module(path: str, prefix: str):
+    """The Python file at ``path`` as a module named ``prefix`` + its
+    file name."""
+    base = os.path.basename(path)[:-3].replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(prefix + base, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(harness_dir: str, name: str):
+    """``read(ctx) -> float | None`` of ``metrics/<name>.py``."""
+    return load_module(os.path.join(harness_dir, "metrics", name + ".py"),
+                       "lasbench_metric_").read
 
 
 def reference_module(harness_dir: str, config: dict):
-    """The configuration's plain reference (a module under ``paths``)."""
-    path = os.path.join(harness_dir, config["reference"])
-    spec = importlib.util.spec_from_file_location(
-        "lasbench_reference_" + os.path.basename(path)[:-3], path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    """The configuration's plain reference (a module under ``paths``).
+    Raises ``ValueError`` where the configuration's graph has edges and
+    the module does not declare ``READS_EDGES = True``: a comparison never
+    leaves an edge out unseen."""
+    mod = load_module(os.path.join(harness_dir, config["reference"]),
+                      "lasbench_reference_")
+    if config["network"].get("edges") and not getattr(mod, "READS_EDGES",
+                                                      False):
+        raise ValueError(f"{config['reference']} does not read the edges "
+                         f"of {config['name']} (no READS_EDGES)")
     return mod

@@ -22,6 +22,11 @@ and these numbers compare the two over all sampled calls together:
   latency_gap_pct sum |per-tick latency - reference| / sum reference
                   latency, per tick and layer
 
+A gap over a scale of 0 (the reference reads no energy, or no latency on
+any tick: a drawn M_L head may read below zero on every row that spikes)
+is 0 where the program reads nothing either, and infinite where it reads
+anything.
+
 A cell compares the numbers its ``limits/<workload>.json`` gives a limit.
 """
 
@@ -43,6 +48,13 @@ def _published(run):
     if run.out_spikes is None:
         raise ValueError("record holds neither layer outputs nor spikes")
     return [(run.out_spikes, len(run.circuits) - 1)]
+
+
+def _share(gap: float, scale: float) -> float:
+    """``gap`` as a percentage of ``scale``; over a scale of 0, exact."""
+    if scale:
+        return 100.0 * gap / scale
+    return 0.0 if gap == 0 else float("inf")
 
 
 def compare(pairs: list, refs: list) -> dict:
@@ -75,10 +87,9 @@ def compare(pairs: list, refs: list) -> dict:
         l_t += float(lat.sum())
     return {"mismatch_pct": 100.0 * diff / max(total, 1),
             "events_gap_pct": 100.0 * ev_d / max(ev_t, 1.0),
-            "energy_gap_pct": 100.0 * e_d / e_t if e_t else float("inf"),
-            "energy_tick_gap_pct": (100.0 * et_d / e_t if e_t
-                                    else float("inf")),
-            "latency_gap_pct": 100.0 * l_d / l_t if l_t else float("inf")}
+            "energy_gap_pct": _share(e_d, e_t),
+            "energy_tick_gap_pct": _share(et_d, e_t),
+            "latency_gap_pct": _share(l_d, l_t)}
 
 
 def run_reference(ref_mod, artifacts, layers, pairs, precision="highest"):

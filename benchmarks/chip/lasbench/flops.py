@@ -1,8 +1,9 @@
 """Algorithm 1's dense operations per network tick, from shapes alone.
 
 Per layer: the drive matmul ``2 * B * fan_in * n_out`` (for a crossbar
-layer, the rows' multiply-accumulate), plus, for every circuit, the seven
-head evaluations of one tick (idle M_ES, M_V; active M_O, M_V, M_ES;
+layer, the rows' multiply-accumulate) and that of each incoming edge,
+``2 * B * n_src * n_dst``, plus, for every circuit, the seven head
+evaluations of one tick (idle M_ES, M_V; active M_O, M_V, M_ES;
 transition M_ED, M_L), each counted as the multiply-adds of the head's
 family at the widths read from the surrogate artifact's arrays. What
 implements them does not change the count.
@@ -33,6 +34,9 @@ def tick_flops(layers: list, batch: int, artifacts: dict) -> float:
     for layer in layers:
         fan_in, n_out = layer["weight"].shape
         total += 2 * batch * fan_in * n_out
+        for edge in layer.get("edges_in", ()):
+            n_src, n_dst = edge["weight"].shape
+            total += 2 * batch * n_src * n_dst
         n = batch * n_out
         if layer["kind"] == "crossbar":
             n *= -(-fan_in // XB_INPUTS)

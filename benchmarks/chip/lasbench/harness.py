@@ -2,14 +2,15 @@
 
 Order of a run: find the cell's files; refuse a device that is not a TPU
 or has too few chips; fix JAX's compilation cache inside the checkout and
-its default matmul precision at float32; draw the surrogate heads from
-``--seed`` and write their artifact; make the configuration's weights on
-the device and the stimulus on the host from ``--seed``; warm up the
-cell's own shapes; freeze the set-up heap out of the garbage collector.
-``setup_s`` ends there. Then the window, traced with
-``--trace 1``; the peak device memory; the device state freed; the plain
-reference over the sampled answers; the metrics; and the result line,
-with each compared number beside its limit.
+its default matmul precision at float32; refuse a graph with edges whose
+plain reference does not read them; draw the surrogate heads from
+``--seed`` and write one artifact per circuit kind; make the
+configuration's weights on the device and the stimulus on the host from
+``--seed``; warm up the cell's own shapes; freeze the set-up heap out of
+the garbage collector. ``setup_s`` ends there. Then the window, traced
+with ``--trace 1``; the peak device memory; the device state freed; the
+plain reference over the sampled answers; the metrics; and the result
+line, with each compared number beside its limit.
 """
 
 from __future__ import annotations
@@ -64,20 +65,21 @@ def use_cache(cache: str):
 
 
 def build_net(cell, seed: int, cache: str, ref_mod) -> Net:
-    """Surrogate heads drawn from ``seed``, written to one artifact that
-    the program and the reference both read; the configuration's weights
-    and graph."""
+    """Surrogate heads drawn from ``seed``, one artifact per circuit
+    kind that the program and the reference both read; the
+    configuration's weights and graph."""
     import jax
     import repro.lasana as lasana
-    kind = cell.config["surrogate"]["circuit"]
-    path = model.write_surrogate(cell.config, seed, os.path.join(
-        cache, "surrogates", cell.config["name"] + ".npz"))
+    paths = {kind: model.write_surrogate(sur, seed, os.path.join(
+        cache, "surrogates", f"{cell.config['name']}.{kind}.npz"))
+        for kind, sur in model.surrogates(cell.config).items()}
     weights = model.make_weights(cell.config)
     return Net(spec=model.build_spec(cell.config, weights),
-               library={kind: lasana.load(path)},
+               library={k: lasana.load(p) for k, p in paths.items()},
                layers=model.reference_layers(cell.config,
                                              jax.device_get(weights)),
-               artifacts={kind: ref_mod.load_artifact(path)})
+               artifacts={k: ref_mod.load_artifact(p)
+                          for k, p in paths.items()})
 
 
 def execute(root: str, workload: str, seed: int, seconds: float,
@@ -101,7 +103,10 @@ def execute(root: str, workload: str, seed: int, seconds: float,
     if src not in sys.path:
         sys.path.insert(0, src)
 
-    ref_mod = cells.reference_module(cell.harness_dir, cell.config)
+    try:
+        ref_mod = cells.reference_module(cell.harness_dir, cell.config)
+    except ValueError as e:
+        return _err(str(e))
     t_net = time.perf_counter()
     net = build_net(cell, seed, cache, ref_mod)
     t_prep = time.perf_counter()

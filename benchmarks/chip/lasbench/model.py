@@ -2,14 +2,15 @@
 
 The surrogate heads are drawn from ``--seed`` here, at the families,
 widths, feature scalings and output ranges the configuration states, and
-written once per run as one artifact in the program's published format
-(``.npz``: arrays keyed ``{head}/{array}`` and a JSON ``__manifest__``).
-The program loads that file with ``lasana.load`` and the plain reference
-reads the same file with numpy: nothing the reference reads is made by
-the program. A spiking output head is then shifted so that every seed's
-neurons fire at the configuration's share of the rows the network feeds
-them: a seed changes the heads, not the amount of work or what the
-comparison can see. The network's weights are the configuration's own, drawn
+written once per run in the program's published format, one artifact per
+circuit kind (``.npz``: arrays keyed ``{head}/{array}`` and a JSON
+``__manifest__``). The program loads each file with ``lasana.load`` and
+the plain reference reads the same file with numpy: nothing the
+reference reads is made by the program. A spiking output head is then
+shifted so that every seed's neurons fire at the configuration's share
+of the rows the network feeds them: a seed changes the heads, not the
+amount of work or what the comparison can see. The network's graph
+(``graph``) and weights are the configuration's own, the weights drawn
 from its ``weights.seed``; the stimulus is drawn from ``--seed``.
 """
 
@@ -120,13 +121,29 @@ def _fire(arrays: dict, head: dict, rows) -> None:
         arrays["y_mu"] = (arrays["y_mu"] + shift).astype(np.float32)
 
 
-def write_surrogate(config: dict, seed: int, path: str) -> str:
-    """Draw the configuration's surrogate heads from ``seed`` and write
-    them to ``path`` (returned). Heads named in ``transition`` see the
-    transition columns too; the derived column comes last. A head with
-    ``fire`` is then shifted to its spike share on the operating rows
-    (``_fire``)."""
-    sur = config["surrogate"]
+def surrogates(config: dict) -> dict:
+    """``{kind: surrogate block}`` of the configuration: its
+    ``surrogates`` object, one block per circuit kind, or its single
+    ``surrogate`` block, which names its ``circuit``."""
+    if "surrogates" not in config:
+        sur = config["surrogate"]
+        return {sur["circuit"]: sur}
+    out = {}
+    for kind, sur in config["surrogates"].items():
+        if sur.get("circuit", kind) != kind:
+            raise ValueError(f"surrogate under {kind!r} names circuit "
+                             f"{sur['circuit']!r}")
+        out[kind] = dict(sur, circuit=kind)
+    return out
+
+
+def write_surrogate(sur: dict, seed: int, path: str) -> str:
+    """Draw one circuit kind's surrogate heads (a block of
+    ``surrogates(config)``) from ``seed`` and write them to ``path``
+    (returned). Each head's draw is seeded by the seed, the circuit and
+    the head's name. Heads named in ``transition`` see the transition
+    columns too; the derived column comes last. A head with ``fire`` is
+    then shifted to its spike share on the operating rows (``_fire``)."""
     base, derived = sur["features"], sur["derived"]
     arrays, families, scales = {}, {}, {}
     for name in sorted(sur["heads"]):
@@ -157,54 +174,142 @@ def write_surrogate(config: dict, seed: int, path: str) -> str:
     return path
 
 
-def make_weights(config: dict) -> list:
-    """Every layer's weight matrix, made on the device in one jitted call
-    from the configuration's weight seed."""
+def graph(config: dict) -> dict:
+    """The configuration's circuit graph in one form: ``{"fan_in",
+    "layers": [{"kind", "n_out", ...}], "edges": [{"src", "dst",
+    "weights"}]}``, and ``spike_amp`` where the configuration states it.
+
+    ``network.spec`` is ``graph_spec`` (the layers as objects: a LIF
+    layer gives ``lif_knobs``; a crossbar layer ``seg_width``,
+    ``adc_bits`` and ``activation``; either may give its own ``weights``
+    recipe), or one of the chain forms ``snn_spec`` and
+    ``crossbar_mlp_spec`` (the layers as widths, the fan-in first, every
+    layer of one kind with the network's settings)."""
+    net = config["network"]
+    spec = net["spec"]
+    if spec == "graph_spec":
+        out = {"fan_in": net["fan_in"], "layers": net["layers"],
+               "edges": net.get("edges", [])}
+    elif spec == "snn_spec":
+        out = {"fan_in": net["layers"][0], "edges": [],
+               "layers": [{"kind": "lif", "n_out": w,
+                           "lif_knobs": net["lif_knobs"]}
+                          for w in net["layers"][1:]]}
+    elif spec == "crossbar_mlp_spec":
+        out = {"fan_in": net["layers"][0], "edges": [],
+               "layers": [{"kind": "crossbar", "n_out": w,
+                           "seg_width": net["seg_width"],
+                           "adc_bits": net["adc_bits"],
+                           "activation": net["activation"]}
+                          for w in net["layers"][1:]]}
+    else:
+        raise ValueError(f"unknown network spec {spec}")
+    if "spike_amp" in net:
+        out["spike_amp"] = net["spike_amp"]
+    return out
+
+
+def _edge_shape(g: dict, edge: dict) -> tuple:
+    """(n_out of the source, the destination's drive width): its n_out
+    for a LIF destination, its fan-in for a crossbar one."""
+    layers = g["layers"]
+    dst = edge["dst"]
+    if layers[dst]["kind"] == "lif":
+        width = layers[dst]["n_out"]
+    else:
+        width = g["fan_in"] if dst == 0 else layers[dst - 1]["n_out"]
+    return layers[edge["src"]]["n_out"], width
+
+
+def make_weights(config: dict) -> tuple:
+    """``(layer weights, edge weights)``, made on the device in one jitted
+    call from the configuration's weight seed. Layer ``i`` draws at
+    ``fold_in(key, i)`` with its own ``weights`` recipe or the
+    configuration's; a drawn edge ``j`` at ``fold_in(key, n_layers +
+    j)``. ``lateral_inhibition`` (``strength`` c) is ``-c * (1 - I)``."""
     import jax
     import jax.numpy as jnp
-    widths = config["network"]["layers"]
-    recipe = config["weights"]
+    g = graph(config)
+    common = config["weights"]
+    widths = [g["fan_in"]] + [l["n_out"] for l in g["layers"]]
+    layer_recipes = [dict(common, **l.get("weights", {}))
+                     for l in g["layers"]]
+    edge_recipes = [dict(common, **e["weights"]) for e in g["edges"]]
+    edge_shapes = [_edge_shape(g, e) for e in g["edges"]]
+
+    def draw(key, i, shape, recipe):
+        a = shape[0]
+        w = jax.random.normal(jax.random.fold_in(key, i), shape,
+                              jnp.float32) * (2.0 / a) ** 0.5
+        if recipe["recipe"] == "he_normal":
+            return w * recipe["gain"]
+        if recipe["recipe"] == "ternary":
+            thr = recipe["threshold_sigma"] * jnp.std(w)
+            return jnp.sign(w) * (jnp.abs(w) > thr)
+        raise ValueError(f"unknown weight recipe {recipe['recipe']}")
 
     @jax.jit
     def make(key):
-        out = []
-        for i, (a, b) in enumerate(zip(widths[:-1], widths[1:])):
-            w = jax.random.normal(jax.random.fold_in(key, i), (a, b),
-                                  jnp.float32) * (2.0 / a) ** 0.5
-            if recipe["recipe"] == "he_normal":
-                w = w * recipe["gain"]
-            elif recipe["recipe"] == "ternary":
-                thr = recipe["threshold_sigma"] * jnp.std(w)
-                w = jnp.sign(w) * (jnp.abs(w) > thr)
+        layers = [draw(key, i, (a, b), r) for i, (a, b, r) in
+                  enumerate(zip(widths[:-1], widths[1:], layer_recipes))]
+        edges = []
+        for j, (shape, r) in enumerate(zip(edge_shapes, edge_recipes)):
+            if r["recipe"] == "lateral_inhibition":
+                if shape[0] != shape[1]:
+                    raise ValueError(f"lateral inhibition needs a square "
+                                     f"edge, not {shape}")
+                edges.append(-r["strength"] * (
+                    1.0 - jnp.eye(shape[0], dtype=jnp.float32)))
             else:
-                raise ValueError(f"unknown weight recipe {recipe['recipe']}")
-            out.append(w)
-        return out
+                edges.append(draw(key, len(layers) + j, shape, r))
+        return layers, edges
 
-    return make(jax.random.key(int(recipe["seed"])))
-
-
-def build_spec(config: dict, weights: list):
-    from repro.core.network import crossbar_mlp_spec, snn_spec
-    net = config["network"]
-    if net["spec"] == "snn_spec":
-        knobs = np.asarray(net["lif_knobs"], np.float32)
-        return snn_spec(weights, [knobs] * len(weights),
-                        spike_amp=net["spike_amp"])
-    if net["spec"] == "crossbar_mlp_spec":
-        return crossbar_mlp_spec(weights, seg_width=net["seg_width"],
-                                 adc_bits=net["adc_bits"],
-                                 activation=net["activation"])
-    raise ValueError(f"unknown network spec {net['spec']}")
+    return make(jax.random.key(int(common["seed"])))
 
 
-def reference_layers(config: dict, weights: list) -> list:
-    """The graph as the plain reference reads it (host copies)."""
-    net = config["network"]
-    kind = "lif" if net["spec"] == "snn_spec" else "crossbar"
-    knobs = np.asarray(net.get("lif_knobs", ()), np.float32)
-    return [{"kind": kind, "weight": np.asarray(w), "knobs": knobs}
-            for w in weights]
+def build_spec(config: dict, weights: tuple):
+    """The program's ``NetworkSpec`` of the graph, through its public
+    builders."""
+    from repro.core.network import (crossbar_layer, graph_spec, lif_layer,
+                                    recurrent_edge)
+    g = graph(config)
+    layer_w, edge_w = weights
+    layers = []
+    for layer, w in zip(g["layers"], layer_w):
+        if layer["kind"] == "lif":
+            layers.append(lif_layer(w, np.asarray(layer["lif_knobs"],
+                                                  np.float32)))
+        elif layer["kind"] == "crossbar":
+            layers.append(crossbar_layer(
+                w, seg_width=layer["seg_width"], adc_bits=layer["adc_bits"],
+                activation=layer["activation"]))
+        else:
+            raise ValueError(f"unknown layer kind {layer['kind']}")
+    edges = [recurrent_edge(e["src"], e["dst"], w)
+             for e, w in zip(g["edges"], edge_w)]
+    amp = {"spike_amp": g["spike_amp"]} if "spike_amp" in g else {}
+    return graph_spec(layers, edges=edges, **amp)
+
+
+def reference_layers(config: dict, weights: tuple) -> list:
+    """The graph as the plain reference reads it (host copies): each
+    layer's kind, weight, LIF knobs and crossbar activation; where the
+    configuration has edges, each layer's incoming ones as ``edges_in``
+    (``[{"src", "weight"}]``)."""
+    g = graph(config)
+    layer_w, edge_w = weights
+    out = []
+    for i, (layer, w) in enumerate(zip(g["layers"], layer_w)):
+        ref = {"kind": layer["kind"], "weight": np.asarray(w),
+               "knobs": np.asarray(layer.get("lif_knobs", ()), np.float32)}
+        if layer["kind"] == "crossbar":
+            ref["activation"] = layer["activation"]
+        if g["edges"]:
+            ref["edges_in"] = [{"src": e["src"], "weight": np.asarray(we)}
+                               for e, we in zip(g["edges"], edge_w)
+                               if e["dst"] == i]
+        out.append(ref)
+    return out
 
 
 def stimulus(config: dict, ticks: int, batch: int, seed: int, *tags

@@ -1,4 +1,12 @@
-"""The one general traffic generator: a mix file names its ``driver``.
+"""The traffic generators: a mix file names its ``driver``.
+
+A driver is the built-in ``closed_loop`` or, for any other name, the class
+``Driver`` of ``drivers/<name>.py`` in the harness directory. It is made
+as ``Driver(cell, net, seed)`` and offers ``prepare()`` (set-up: the
+stimulus and the warm-up of every shape), ``window(seconds)`` (the
+counters: at least ``attempted``, ``failed`` and each end-to-end metric
+of the cell but ``setup_s``), ``close()`` and ``check_pairs()`` (the
+(program record, stimulus) pairs the comparison reads).
 
 closed_loop  back-to-back ``lasana.simulate`` calls over a stimulus pool
              of ``pool`` batches of ``batch`` lanes x ``ticks`` ticks; the
@@ -10,11 +18,12 @@ closed_loop  back-to-back ``lasana.simulate`` calls over a stimulus pool
 
 from __future__ import annotations
 
+import os
 import time
 
 import numpy as np
 
-from lasbench import model
+from lasbench import cells, model
 from lasbench.data import sub_seed
 
 
@@ -80,8 +89,21 @@ class ClosedLoop:
         return [(run, self.pool[i % len(self.pool)]) for i, run in self.samples]
 
 
-DRIVERS = {"closed_loop": ClosedLoop}
+BUILT_IN = {"closed_loop": ClosedLoop}
+
+
+def driver_class(harness_dir: str, name: str):
+    """The driver ``name``: built in, or ``drivers/<name>.py``'s
+    ``Driver``."""
+    if name in BUILT_IN:
+        return BUILT_IN[name]
+    if not cells.NAME.fullmatch(name):
+        raise ValueError(f"driver name {name!r} is not a name")
+    return cells.load_module(os.path.join(harness_dir, "drivers",
+                                          name + ".py"),
+                             "lasbench_driver_").Driver
 
 
 def driver(cell, net, seed: int):
-    return DRIVERS[cell.traffic["driver"]](cell, net, seed)
+    return driver_class(cell.harness_dir, cell.traffic["driver"])(
+        cell, net, seed)

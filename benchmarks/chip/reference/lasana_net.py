@@ -9,13 +9,22 @@ feature and derived column is recomputed here.
 
 One network tick, per layer, in graph order:
 
-  lif       drive = (u @ W) / V_dd, clipped to [-1, 1]; a neuron has an
-            input event when a presynaptic spike (|u| > V_dd / 2) arrives
+  lif       drive = (u @ W) / V_dd, clipped to [-1, 1]; u is the previous
+            layer's spikes, or its codes through its activation x V_dd; a
+            neuron has an input event when a live presynaptic line (a
+            spike, |u| > V_dd / 2; a code, |u| > V_dd / 20) arrives
             through a nonzero weight; circuit inputs (drive, V_dd, 5)
-  crossbar  the previous layer's codes through tanh, x 0.8 V (the stimulus
-            as given for the first layer), clipped to +-0.8 V, cut into
+  crossbar  the previous layer's codes through its activation (tanh or
+            none), x 0.8 V, or its spikes x 0.8 V / V_dd (the stimulus as
+            given for the first layer), clipped to +-0.8 V, cut into
             32-input row segments; a row has an input event when any of
             its lines is live (|x| > 1e-6)
+  edges     an edge into a layer carries its source layer's output of
+            the tick before (zeros on the first tick), adapted as above:
+            into a lif layer, (u_src @ W_edge) / V_dd adds to the drive
+            before the clip, and its live lines add input events; into a
+            crossbar layer, u_src @ W_edge adds to the volts before the
+            clip
   Alg. 1    stale event-receiving circuits catch up with one merged idle
             event (M_ES, M_V at zero input and the idle gap tau); then
             M_O, M_V, M_ES on the active rows and M_ED, M_L on the
@@ -61,6 +70,7 @@ XB_EVENT_EPS = 1e-6
 XB_OUT_EPS = 0.02
 
 PRECISIONS = ("highest", "high", "bf16")
+READS_EDGES = True               # layers' ``edges_in`` are simulated
 
 
 def load_artifact(path: str) -> dict:
@@ -166,6 +176,41 @@ def alg1(heads, kind, state, changed, x, t, precision):
     return new, e, lat
 
 
+def _act(y, activation: str):
+    """A crossbar layer's digital activation of its codes."""
+    if activation == "tanh":
+        return jnp.tanh(y)
+    if activation == "none":
+        return y
+    raise ValueError(f"reference has no {activation!r} activation")
+
+
+def _to_lif(src: str, activation: str, y):
+    """A source's output as lif drive: spikes as they are, codes through
+    the source's activation x V_dd."""
+    if src in ("input", "lif"):
+        return y
+    return _act(y, activation) * LIF_VDD
+
+
+def _to_crossbar(src: str, activation: str, y):
+    """A source's output as crossbar input volts."""
+    if src == "input":
+        return y
+    if src == "lif":
+        return y * (XB_IN_HI / LIF_VDD)
+    return _act(y, activation) * XB_IN_HI
+
+
+def _hits(u, src: str, w):
+    """(B, n_out) bool: a live line of ``u`` reaches the neuron through a
+    nonzero weight of ``w``."""
+    thr = 0.5 * LIF_VDD if src in ("input", "lif") else 0.05 * LIF_VDD
+    pre = (jnp.abs(u) > thr).astype(jnp.float32)
+    conn = (jnp.abs(w) > 0).astype(jnp.float32)
+    return dot(pre, conn, "highest") > 0.5
+
+
 def _row_params(w: np.ndarray) -> np.ndarray:
     """(fan_in, n_out) ternary matrix -> (n_out * n_seg, 33) row knobs:
     row (j, s) holds weights fan_in[32 s : 32 s + 32] of output j, then a
@@ -179,10 +224,18 @@ def _row_params(w: np.ndarray) -> np.ndarray:
                           ).astype(np.float32)
 
 
-@partial(jax.jit, static_argnames=("kinds", "n_out", "precision"))
-def _run(heads, weights, knobs, stimulus, t_end, *, kinds, n_out,
-         precision):
+@partial(jax.jit, static_argnames=("kinds", "n_out", "acts", "edges",
+                                   "precision"))
+def _run(heads, weights, knobs, edge_w, stimulus, t_end, *, kinds, n_out,
+         acts, edges, precision):
     t_steps, b, _ = stimulus.shape
+    into = [[j for j, (_, dst) in enumerate(edges) if dst == i]
+            for i in range(len(kinds))]
+    sources = {src for src, _ in edges}
+    # each edge source's output of the tick before; None where no edge
+    # leaves the layer
+    prev = tuple(jnp.zeros((b, n_out[i]), jnp.float32) if i in sources
+                 else None for i in range(len(kinds)))
     states = []
     for i, kind in enumerate(kinds):
         if kind == "lif":          # one knob set for the whole layer
@@ -195,21 +248,23 @@ def _run(heads, weights, knobs, stimulus, t_end, *, kinds, n_out,
         z = jnp.zeros((n,), jnp.float32)
         states.append((z, z, z, p))
 
-    def tick(states, xs):
+    def tick(carry, xs):
+        states, prev = carry
         u_in, k = xs
-        cur, src = u_in, "input"
+        cur, src, act = u_in, "input", "tanh"
         new_states, pubs, es, ls, evs = [], [], [], [], []
         for i, kind in enumerate(kinds):
             t = (k + 1.0) * (LIF_CLOCK_NS if kind == "lif" else XB_CLOCK_NS)
             if kind == "lif":
-                u = cur if src in ("input", "lif") else \
-                    jnp.tanh(cur) * LIF_VDD
-                thr = 0.5 * LIF_VDD if src in ("input", "lif") \
-                    else 0.05 * LIF_VDD
+                u = _to_lif(src, act, cur)
                 drive = dot(u, weights[i], precision) / LIF_VDD
-                pre = (jnp.abs(u) > thr).astype(jnp.float32)
-                conn = (jnp.abs(weights[i]) > 0).astype(jnp.float32)
-                changed = (dot(pre, conn, "highest") > 0.5).reshape(-1)
+                hit = _hits(u, src, weights[i])
+                for j in into[i]:
+                    s = edges[j][0]
+                    ur = _to_lif(kinds[s], acts[s], prev[s])
+                    drive = drive + dot(ur, edge_w[j], precision) / LIF_VDD
+                    hit = hit | _hits(ur, kinds[s], edge_w[j])
+                changed = hit.reshape(-1)
                 d = jnp.clip(drive, -1.0, 1.0).reshape(-1)
                 x = jnp.stack([d, jnp.full_like(d, LIF_VDD),
                                jnp.full_like(d, LIF_SPIKES_PER_PERIOD)], 1)
@@ -217,12 +272,11 @@ def _run(heads, weights, knobs, stimulus, t_end, *, kinds, n_out,
                                   t, precision)
                 pub = jnp.where(changed, st[1], 0.0).reshape(b, -1)
             else:
-                if src == "input":
-                    xv = cur
-                elif src == "lif":
-                    xv = cur * (XB_IN_HI / LIF_VDD)
-                else:
-                    xv = jnp.tanh(cur) * XB_IN_HI
+                xv = _to_crossbar(src, act, cur)
+                for j in into[i]:
+                    s = edges[j][0]
+                    xv = xv + dot(_to_crossbar(kinds[s], acts[s], prev[s]),
+                                  edge_w[j], precision)
                 xv = jnp.clip(xv, -XB_IN_HI, XB_IN_HI)
                 fan_in = weights[i].shape[0]
                 n_seg = -(-fan_in // XB_INPUTS)
@@ -243,12 +297,15 @@ def _run(heads, weights, knobs, stimulus, t_end, *, kinds, n_out,
             es.append(e.reshape(b, -1).sum(1))
             ls.append(lat.reshape(b, -1).max(1))
             evs.append(changed.reshape(b, -1).sum(1, dtype=jnp.int32))
-            cur, src = pub, kind
-        return new_states, (tuple(pubs), jnp.stack(es), jnp.stack(ls),
-                            jnp.stack(evs))
+            cur, src, act = pub, kind, acts[i]
+        new_prev = tuple(None if p is None else pubs[i]
+                         for i, p in enumerate(prev))
+        return (new_states, new_prev), (tuple(pubs), jnp.stack(es),
+                                        jnp.stack(ls), jnp.stack(evs))
 
     ks = jnp.arange(t_steps, dtype=jnp.float32)
-    states, (pubs, es, ls, evs) = jax.lax.scan(tick, states, (stimulus, ks))
+    (states, _), (pubs, es, ls, evs) = jax.lax.scan(
+        tick, (states, prev), (stimulus, ks))
     flush = []
     for i, kind in enumerate(kinds):
         if kind != "lif":
@@ -268,7 +325,11 @@ def simulate(artifacts: dict, layers: list, stimulus, *,
 
     artifacts  {circuit kind: load_artifact(...)}
     layers     [{"kind": "lif", "weight": (fan_in, n_out), "knobs": (4,)}
-                | {"kind": "crossbar", "weight": (fan_in, n_out) ternary}]
+                | {"kind": "crossbar", "weight": (fan_in, n_out) ternary,
+                   "activation": "tanh" (default) | "none"}], each with
+               optional "edges_in": [{"src": layer index, "weight":
+               (n_out[src], n_out) into lif, (n_out[src], fan_in) into
+               crossbar}]
     stimulus   (T, B, fan_in) drive of the first layer
 
     Returns per-row records: ``published`` [(T, B, n_out) per layer],
@@ -281,6 +342,11 @@ def simulate(artifacts: dict, layers: list, stimulus, *,
     t_end = jnp.full((b,), t_steps, jnp.float32)
     kinds = tuple(l["kind"] for l in layers)
     n_out = tuple(int(np.shape(l["weight"])[1]) for l in layers)
+    acts = tuple(l.get("activation", "tanh") for l in layers)
+    edges_in = [(e["src"], i, e["weight"]) for i, l in enumerate(layers)
+                for e in l.get("edges_in", ())]
+    edges = tuple((src, dst) for src, dst, _ in edges_in)
+    edge_w = tuple(jnp.asarray(w, jnp.float32) for _, _, w in edges_in)
     heads = {k: {n: {"family": h["family"], "scale": h["scale"],
                      "arrays": {a: jnp.asarray(v) for a, v in
                                 h["arrays"].items()}}
@@ -291,8 +357,8 @@ def simulate(artifacts: dict, layers: list, stimulus, *,
                   for l in layers)
     weights = tuple(jnp.asarray(l["weight"], jnp.float32) for l in layers)
     pubs, es, ls, evs, flush = jax.device_get(_run(
-        _Heads(heads), weights, knobs, x, t_end, kinds=kinds,
-        n_out=n_out, precision=precision))
+        _Heads(heads), weights, knobs, edge_w, x, t_end, kinds=kinds,
+        n_out=n_out, acts=acts, edges=edges, precision=precision))
     return {"published": [np.asarray(p) for p in pubs],
             "energy": np.asarray(es, np.float64),
             "latency": np.asarray(ls, np.float64),

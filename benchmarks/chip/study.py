@@ -62,9 +62,11 @@ def control(args):
             cpairs = [(check.as_record(cr, run), x)
                       for (run, x), cr in zip(pairs, crefs)]
             line["control_" + prec] = check.compare(cpairs, refs)
-        if cell.config["surrogate"]["circuit"] == "lif":
-            line["spike_share"] = [float((r > 0.75).mean())
-                                   for r in refs[0]["published"]]
+        if "lif" in net.artifacts:
+            line["spike_share"] = [
+                float((r > 0.75).mean())
+                for r, layer in zip(refs[0]["published"], net.layers)
+                if layer["kind"] == "lif"]
         line["seconds"] = time.perf_counter() - t0
         print(json.dumps(line), flush=True)
         for side, nums in line.items():

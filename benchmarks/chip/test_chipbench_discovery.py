@@ -9,7 +9,7 @@ import shutil
 
 import pytest
 
-from lasbench import cells, check
+from lasbench import cells, check, tiny, traffic
 
 HARNESS = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HARNESS))
@@ -54,7 +54,8 @@ def test_benchmark_json_shape():
                          [w["name"] for w in _bench()["workloads"]])
 def test_every_workload_resolves(workload):
     cell = cells.resolve(ROOT, workload)
-    assert cell.traffic["driver"] == "closed_loop"
+    assert callable(traffic.driver_class(cell.harness_dir,
+                                         cell.traffic["driver"]))
     compared = set(cell.limits) - {"readings"}
     assert compared <= set(check.NAMES)
     assert compared >= {"mismatch_pct", "events_gap_pct", "latency_gap_pct"}
@@ -121,6 +122,43 @@ def test_new_cell_from_new_files_only(tmp_path):
     assert read({"counters": {"calls": 6, "window_s": 2.0}}) == 3.0
     after = _digest(harness)
     assert {k: after[k] for k in before} == before
+
+
+def test_graph_cell_from_new_files_only(tmp_path):
+    """A mixed graph with a recurrent edge, a surrogate per kind, its own
+    reference and a driver file enters by new files and new entries."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    harness = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(HARNESS, harness, ignore=shutil.ignore_patterns(
+        ".cache", "__pycache__"))
+    before = _digest(harness)
+    workload = tiny.add_graph_cell(str(tmp_path))
+
+    cell = cells.resolve(str(tmp_path), workload)
+    assert cell.config["network"]["edges"]
+    assert set(cell.config["surrogates"]) == {"lif", "crossbar"}
+    drv = traffic.driver_class(cell.harness_dir, cell.traffic["driver"])
+    assert drv is not traffic.ClosedLoop
+    assert issubclass(drv, traffic.ClosedLoop)
+    ref = cells.reference_module(cell.harness_dir, cell.config)
+    assert ref.READS_EDGES
+    assert [m["name"] for m in cell.per_layer] == \
+        [m["name"] for m in _bench()["per_layer"]]
+    after = _digest(harness)
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == {
+        os.path.join("configs", tiny.GRAPH + ".json"),
+        os.path.join("traffic", "closed-4x20-file.json"),
+        os.path.join("limits", workload + ".json"),
+        tiny.GRAPH_REFERENCE,
+        os.path.join("drivers", tiny.GRAPH_DRIVER + ".py")}
+
+
+def test_unknown_driver_is_refused(tmp_path):
+    with pytest.raises(FileNotFoundError):
+        traffic.driver_class(str(tmp_path), "no_such_driver")
+    with pytest.raises(ValueError):
+        traffic.driver_class(str(tmp_path), "../x")
 
 
 def test_unknown_workload_is_refused():

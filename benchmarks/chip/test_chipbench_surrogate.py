@@ -1,5 +1,5 @@
-"""The surrogate heads the harness draws from the seed: one artifact, the
-same arrays for the same seed, read alike by the program and by the plain
+"""The surrogate heads the harness draws from the seed: one artifact per
+circuit kind, the same arrays for the same seed, read alike by the program and by the plain
 reference, whose head arithmetic matches the program's head by head."""
 
 import os
@@ -26,32 +26,40 @@ def _arrays(path):
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_artifact_is_drawn_from_the_seed(tmp_path, name):
-    cfg = _config(name)
-    a = _arrays(model.write_surrogate(cfg, SEED, str(tmp_path / "a.npz")))
-    b = _arrays(model.write_surrogate(cfg, SEED, str(tmp_path / "b.npz")))
-    c = _arrays(model.write_surrogate(cfg, SEED + 1, str(tmp_path / "c.npz")))
-    assert a.keys() == b.keys() == c.keys()
-    assert all(np.array_equal(a[k], b[k]) for k in a)
-    assert any(not np.array_equal(a[k], c[k]) for k in a
-               if k != "__manifest__")
-    assert all(v.dtype == np.float32 for k, v in a.items()
-               if k != "__manifest__")
+    for kind, sur in model.surrogates(_config(name)).items():
+        a, b, c = (_arrays(model.write_surrogate(
+            sur, seed, str(tmp_path / f"{kind}.{tag}.npz")))
+            for tag, seed in (("a", SEED), ("b", SEED), ("c", SEED + 1)))
+        assert a.keys() == b.keys() == c.keys()
+        assert all(np.array_equal(a[k], b[k]) for k in a)
+        assert any(not np.array_equal(a[k], c[k]) for k in a
+                   if k != "__manifest__")
+        assert all(v.dtype == np.float32 for k, v in a.items()
+                   if k != "__manifest__")
+
+
+def _kinds(name):
+    """(kind, surrogate block, id prefix) of each circuit kind: the
+    configuration's name alone where it has one kind."""
+    surs = model.surrogates(_config(name))
+    return [(kind, sur, name if len(surs) == 1 else f"{name}-{kind}")
+            for kind, sur in sorted(surs.items())]
 
 
 def _head_cases():
-    out = []
-    for name in sorted(CONFIGS):
-        for head in sorted(_config(name)["surrogate"]["heads"]):
-            out.append((name, head))
-    return out
+    return [pytest.param(name, kind, head, id=f"{prefix}-{head}")
+            for name in sorted(CONFIGS)
+            for kind, sur, prefix in _kinds(name)
+            for head in sorted(sur["heads"])]
 
 
-@pytest.mark.parametrize("name,head", _head_cases())
-def test_program_and_reference_read_one_head_alike(tmp_path, name, head):
+@pytest.mark.parametrize("name,kind,head", _head_cases())
+def test_program_and_reference_read_one_head_alike(tmp_path, name, kind,
+                                                   head):
     import repro.lasana as lasana
     cfg = _config(name)
-    sur = cfg["surrogate"]
-    path = model.write_surrogate(cfg, SEED, str(tmp_path / "s.npz"))
+    sur = model.surrogates(cfg)[kind]
+    path = model.write_surrogate(sur, SEED, str(tmp_path / "s.npz"))
     ref_mod = cells.reference_module(HARNESS, cfg)
     ref = ref_mod.load_artifact(path)["heads"][head]
 
@@ -75,22 +83,24 @@ def test_program_and_reference_read_one_head_alike(tmp_path, name, head):
 
 
 def _fire_cases():
-    return [(name, head, seed) for name in sorted(CONFIGS)
-            for head, h in sorted(_config(name)["surrogate"]["heads"].items())
+    return [pytest.param(name, kind, head, seed, id=f"{prefix}-{head}-{seed}")
+            for name in sorted(CONFIGS)
+            for kind, sur, prefix in _kinds(name)
+            for head, h in sorted(sur["heads"].items())
             if "fire" in h for seed in (1328774840, 3500000014, SEED)]
 
 
-@pytest.mark.parametrize("name,head,seed", _fire_cases())
-def test_spiking_head_fires_at_its_share_on_every_seed(tmp_path, name, head,
-                                                       seed):
+@pytest.mark.parametrize("name,kind,head,seed", _fire_cases())
+def test_spiking_head_fires_at_its_share_on_every_seed(tmp_path, name, kind,
+                                                       head, seed):
     """Fresh operating rows, not those the shift was fitted on, read above
     the threshold at the stated share, whichever way the seed's head
     leans (the first two seeds drew heads that never fired before)."""
     cfg = _config(name)
-    sur = cfg["surrogate"]
+    sur = model.surrogates(cfg)[kind]
     h = sur["heads"][head]
     ref = cells.reference_module(HARNESS, cfg).load_artifact(
-        model.write_surrogate(cfg, seed, str(tmp_path / "s.npz")))
+        model.write_surrogate(sur, seed, str(tmp_path / "s.npz")))
     names, mu, _, _ = model._columns(sur["features"] + sur["derived"])
     rows = model._operating_rows(sur, names, mu, np.random.default_rng(7))
     y = model._forward(ref["heads"][head]["arrays"], h["family"], rows)
