@@ -1421,15 +1421,16 @@ class NetworkEngine:
                                ).astype(jnp.float32)
                         incoming = (pre @ ff_conn[i]) > 0.5
                         for src, we, conn in rec[i]:
-                            ur = adapt_signal(
-                                kinds[src], "lif", prev_ys[src],
-                                spike_amp=amp,
-                                activation=src_activation(src))
-                            drive = drive + (ur @ we) / amp
-                            pr = (jnp.abs(ur)
-                                  > event_threshold(kinds[src], amp)
-                                  ).astype(jnp.float32)
-                            incoming = incoming | ((pr @ conn) > 0.5)
+                            with jax.named_scope("edges"):
+                                ur = adapt_signal(
+                                    kinds[src], "lif", prev_ys[src],
+                                    spike_amp=amp,
+                                    activation=src_activation(src))
+                                drive = drive + (ur @ we) / amp
+                                pr = (jnp.abs(ur)
+                                      > event_threshold(kinds[src], amp)
+                                      ).astype(jnp.float32)
+                                incoming = incoming | ((pr @ conn) > 0.5)
                         if live is not None:
                             incoming = incoming & live[:, None]
                         changed = incoming.reshape(-1)
@@ -1444,10 +1445,11 @@ class NetworkEngine:
                                           spike_amp=amp,
                                           activation=src_activation(src_idx))
                         for src, we, _ in rec[i]:
-                            xv = xv + adapt_signal(
-                                kinds[src], "crossbar", prev_ys[src],
-                                spike_amp=amp,
-                                activation=src_activation(src)) @ we
+                            with jax.named_scope("edges"):
+                                xv = xv + adapt_signal(
+                                    kinds[src], "crossbar", prev_ys[src],
+                                    spike_amp=amp,
+                                    activation=src_activation(src)) @ we
                         xv = jnp.clip(xv, circ.input_lo, circ.input_hi)
                         if live is not None:
                             xv = jnp.where(live[:, None], xv, 0.0)

@@ -292,6 +292,71 @@ def test_recurrent_edge_one_tick_delay():
     np.testing.assert_array_equal(r, np.arange(12) % 2 == 0)   # alternation
 
 
+def _op_names(spec, x):
+    """op_name of every dot and of every operation of the compiled
+    programs of one behavioral run of ``spec`` on ``x``."""
+    eng = NetworkEngine(spec, backend="behavioral")
+    eng.run(x)
+    dots, names = [], []
+    for text in eng.compiled_hlo():
+        for line in text.splitlines():
+            name = line.partition('op_name="')[2].partition('"')[0]
+            names.append(name)
+            if " dot(" in line or " convolution(" in line:
+                dots.append(name)
+    return dots, names
+
+
+@pytest.mark.parametrize("dst_kind", ["lif", "crossbar"])
+def test_edge_work_is_scoped_edges_inside_drive(dst_kind):
+    """A delayed edge's work lowers under the ``drive/edges`` stage scope:
+    into a LIF layer both its drive product and its event-detection
+    product; into a crossbar layer its volts product. The same graph
+    without the edge names no ``edges``."""
+    rng = np.random.default_rng(17)
+    params = jnp.asarray([0.58, 0.5, 0.5, 0.5], jnp.float32)
+    if dst_kind == "lif":
+        layers = [lif_layer(rng.normal(0, 0.5, (12, 16)).astype(np.float32),
+                            params)]
+        edge = recurrent_edge(0, 0, -0.6 * (1 - np.eye(16, dtype=np.float32)))
+        x = jnp.asarray(rng.choice([0.0, 1.5], (6, 3, 12)), jnp.float32)
+        n_edge_dots = 2
+    else:
+        layers = [crossbar_layer(rng.integers(-1, 2, (40, 8)
+                                              ).astype(np.float32)),
+                  lif_layer(rng.normal(0, 0.5, (8, 6)).astype(np.float32),
+                            params)]
+        edge = recurrent_edge(1, 0, rng.normal(0, 0.1, (6, 40)
+                                               ).astype(np.float32))
+        x = jnp.asarray(rng.uniform(-0.8, 0.8, (6, 3, 40)), jnp.float32)
+        n_edge_dots = 1
+    dots, _ = _op_names(graph_spec(layers, edges=[edge]), x)
+    scoped = [d for d in dots if "/drive/edges/" in d]
+    assert len(scoped) >= n_edge_dots, dots
+    assert all("/edges/" not in d for d in dots if d not in scoped)
+    _, names = _op_names(graph_spec(layers), x)
+    assert not any("edges" in n for n in names)
+
+
+def test_chains_without_edges_name_no_edges_scope():
+    """snn- and crossbar-MLP-shaped chains (the benchmark's accepted
+    cells' forms) carry no ``edges`` scope anywhere in their programs."""
+    rng = np.random.default_rng(5)
+    params = jnp.asarray([0.58, 0.5, 0.5, 0.5], jnp.float32)
+    snn = snn_spec([rng.normal(0, 0.5, (12, 8)).astype(np.float32),
+                    rng.normal(0, 0.5, (8, 4)).astype(np.float32)],
+                   [params, params])
+    xbar = crossbar_mlp_spec([rng.integers(-1, 2, (40, 8)).astype(np.float32),
+                              rng.integers(-1, 2, (8, 4)).astype(np.float32)])
+    for spec, x in ((snn, jnp.asarray(rng.choice([0.0, 1.5], (5, 2, 12)),
+                                      jnp.float32)),
+                    (xbar, jnp.asarray(rng.uniform(-0.8, 0.8, (5, 2, 40)),
+                                       jnp.float32))):
+        _, names = _op_names(spec, x)
+        assert any("/drive/" in n for n in names)
+        assert not any("edges" in n for n in names)
+
+
 def test_adapter_shape_dtype_round_trips():
     """Every (src, dst) adapter preserves shape + float32 and lands in the
     destination's native range."""
