@@ -103,7 +103,7 @@ from repro.core.circuits import CrossbarRow, LIFNeuron, get_circuit
 from repro.core.distributed import batch_spec, shard_over_batch
 from repro.core.surrogate import Surrogate, SurrogateLibrary, as_surrogate
 from repro.core.wrapper import (LasanaState, RowBlocks, init_state,
-                                lasana_step, row_blocks_ok)
+                                lasana_step, row_blocks_ok, stale_circuits)
 
 P_REPL = P()                     # replicated diagnostics spec
 BACKENDS = ("golden", "behavioral", "lasana")
@@ -337,6 +337,15 @@ def _count_events(changed) -> jax.Array:
     events once a tick/layer exceeds 2^24 of them (dry-run scales reach
     2^27 circuits); int32 keeps every count exact to 2^31."""
     return jnp.sum(changed, dtype=jnp.int32)
+
+
+def _any_stale(backend: str, carry, changed, t, clock_ns) -> jax.Array:
+    """Whether some circuit of a layer is stale this tick, due Algorithm
+    1's idle catch-up: the default standalone tick evaluates that stage
+    only then. Only the lasana backend has the stage."""
+    if backend != "lasana":
+        return jnp.zeros((), bool)
+    return jnp.any(stale_circuits(carry, changed, t, clock_ns))
 
 
 def _tile_params(p, b: int, n_out: int):
@@ -1150,14 +1159,17 @@ class NetworkEngine:
 
     def _lif_tick(self, i: int, slot_records: bool = False):
         """Returns tick(carry, drive, changed, k, bank, pack, layout) ->
-        (carry', spikes (B, n), e, l, events); ``drive`` is the
+        (carry', spikes (B, n), e, l, events, stale); ``drive`` is the
         pre-combined synaptic drive and ``bank`` the layer kind's (traced)
         Surrogate, None outside the lasana backend. ``pack``/``layout``
         are the kind's megakernel head pack (built once per program call
         by :meth:`_mk_pack`) or None for the stacked-dispatch path.
         ``slot_records`` switches the event count from one scalar to a
         per-batch-slot (B,) int32 vector (the continuous-batching server
-        attributes records per tenant; layouts are batch-major)."""
+        attributes records per tenant; layouts are batch-major).
+        ``stale`` is a bool scalar: whether some circuit is stale
+        (:func:`stale_circuits`), due Algorithm 1's idle catch-up; False
+        outside the lasana backend."""
         layer = self.spec.layers[i]
         amp = self.spec.spike_amp
         circ = self.circs[i]
@@ -1174,6 +1186,7 @@ class NetworkEngine:
             with jax.named_scope("drive"):
                 xin = drive_to_circuit_inputs(drive, spike_amp=amp
                                               ).reshape(-1, 3)
+            stale = _any_stale(backend, carry, changed, t, clock)
 
             if backend == "golden":
                 state, params = carry
@@ -1220,14 +1233,15 @@ class NetworkEngine:
                                  axis=1, dtype=jnp.int32)
                 else:
                     ev = _count_events(changed)
-            return carry, spikes, e, l, ev
+            return carry, spikes, e, l, ev, stale
 
         return tick
 
     def _xbar_tick(self, i: int, slot_records: bool = False):
         """Returns tick(carry, x_volts (B, fan_in), k, bank, pack, layout)
-        -> (carry', codes (B, n_out), e, l, events); ``bank``/``pack``/
-        ``layout``/``slot_records`` as in :meth:`_lif_tick`.
+        -> (carry', codes (B, n_out), e, l, events, stale); ``bank``/
+        ``pack``/``layout``/``slot_records``/``stale`` as in
+        :meth:`_lif_tick`.
 
         Rows are combinational with sample-and-hold inputs: a row-segment
         fires an input event iff any of its input lines is live (|x| > eps)
@@ -1266,6 +1280,7 @@ class NetworkEngine:
                 live = jnp.any(jnp.abs(x_seg) > _XBAR_EVENT_EPS, axis=-1)
                 changed = jnp.broadcast_to(live[:, None],
                                            (b_l, n_out, n_seg)).reshape(-1)
+            stale = _any_stale(backend, carry, changed, t, clock)
 
             if by_blocks(bank, pack):
                 rb = RowBlocks(x=x_seg[:, None],
@@ -1320,7 +1335,7 @@ class NetworkEngine:
                                  axis=1, dtype=jnp.int32)
                 else:
                     ev = _count_events(changed)
-            return carry, y, e, l, ev
+            return carry, y, e, l, ev, stale
 
         return tick
 
@@ -1356,8 +1371,10 @@ class NetworkEngine:
         """Build the one-network-tick cascade shared by every program.
 
         Returns ``cascade(banks, carries, prev_ys, u_in, k) ->
-        (new_carries, new_ys, e (L,), l (L,), events (L,) int32)`` — the
-        exact per-tick dataflow (adapters, event detection, bank steps).
+        (new_carries, new_ys, e (L,), l (L,), events (L,) int32,
+        stale (L,) bool)`` — the exact per-tick dataflow (adapters, event
+        detection, bank steps); ``stale`` marks the layers with a stale
+        circuit (:func:`stale_circuits`).
         The monolithic program and the streaming chunk program both scan
         THIS closure, which is what makes chunked runs bit-identical to
         monolithic ones.
@@ -1407,7 +1424,7 @@ class NetworkEngine:
             bsz = u_in.shape[0]
             cur, src_kind, src_idx = u_in, "input", None
             new_carries, new_ys = [], []
-            es, ls, evs = [], [], []
+            es, ls, evs, stales = [], [], [], []
             for i in range(n_layers):
                 layer = spec.layers[i]
                 pk, ly = packs.get(kinds[i], (None, None))
@@ -1434,10 +1451,10 @@ class NetworkEngine:
                         if live is not None:
                             incoming = incoming & live[:, None]
                         changed = incoming.reshape(-1)
-                    carry, y, e, l, ev = ticks[i](carries[i], drive,
-                                                  changed, k,
-                                                  banks.get(kinds[i]),
-                                                  pk, ly)
+                    carry, y, e, l, ev, st = ticks[i](carries[i], drive,
+                                                      changed, k,
+                                                      banks.get(kinds[i]),
+                                                      pk, ly)
                 else:
                     circ = self.circs[i]
                     with jax.named_scope("drive"):
@@ -1453,9 +1470,9 @@ class NetworkEngine:
                         xv = jnp.clip(xv, circ.input_lo, circ.input_hi)
                         if live is not None:
                             xv = jnp.where(live[:, None], xv, 0.0)
-                    carry, y, e, l, ev = ticks[i](carries[i], xv, k,
-                                                  banks.get(kinds[i]),
-                                                  pk, ly)
+                    carry, y, e, l, ev, st = ticks[i](carries[i], xv, k,
+                                                      banks.get(kinds[i]),
+                                                      pk, ly)
                 new_carries.append(carry)
                 new_ys.append(y)
                 with jax.named_scope("update"):
@@ -1466,9 +1483,11 @@ class NetworkEngine:
                         es.append(jnp.sum(e))
                         ls.append(jnp.max(l))
                 evs.append(ev)
+                stales.append(st)
                 cur, src_kind, src_idx = y, kinds[i], i
             with jax.named_scope("update"):
-                records = jnp.stack(es), jnp.stack(ls), jnp.stack(evs)
+                records = (jnp.stack(es), jnp.stack(ls), jnp.stack(evs),
+                           jnp.stack(stales))
             return (new_carries, new_ys, *records)
 
         return cascade
@@ -1518,7 +1537,8 @@ class NetworkEngine:
         ``megakernel_chunk``, whose jnp body is a ``lax.scan`` of the
         exact per-tick step (bit-identical to the generic scan) and whose
         Pallas body keeps v/o/t_last VMEM-resident across the chunk.
-        Returns the same ``((carries, prev_ys), outs)`` as the scan."""
+        Returns the same ``((carries, prev_ys), outs)`` as the scan, with
+        None for its stale flags."""
         from repro.kernels.tick_megakernel import megakernel_chunk
         spec = self.spec
         layer = spec.layers[0]
@@ -1544,7 +1564,9 @@ class NetworkEngine:
         es = jnp.sum(e_seq, axis=1)[:, None]
         ls = jnp.max(l_seq, axis=1)[:, None]
         evs = jnp.sum(changed_seq, axis=1, dtype=jnp.int32)[:, None]
-        out = (spikes, (spikes,) if self.record_hidden else (), es, ls, evs)
+        # the stale flags are the scan's alone (None: not counted here)
+        out = (spikes, (spikes,) if self.record_hidden else (), es, ls, evs,
+               None)
         return ([new_state], [spikes[-1]]), out
 
     def _scan_chunk(self, cascade, banks, carries, prev_ys, input_seq, ks):
@@ -1564,11 +1586,11 @@ class NetworkEngine:
         def tick(state, xs):
             carries, prev_ys = state
             u_in, k = xs
-            new_carries, new_ys, es, ls, evs = cascade(
+            new_carries, new_ys, es, ls, evs, stales = cascade(
                 banks, carries, prev_ys, u_in, k, packs)
             out = (new_ys[-1],
                    tuple(new_ys) if record_hidden else (),
-                   es, ls, evs)
+                   es, ls, evs, stales)
             return (new_carries, new_ys), out
 
         return jax.lax.scan(tick, (list(carries), list(prev_ys)),
@@ -1609,7 +1631,7 @@ class NetworkEngine:
             self._trace_count += 1
             t_steps = input_seq.shape[0]
             ks = jnp.arange(t_steps, dtype=jnp.float32)
-            (carries, _), (out_seq, hidden, e_tl, l_tl, ev_tl) = \
+            (carries, _), (out_seq, hidden, e_tl, l_tl, ev_tl, st_tl) = \
                 self._scan_chunk(cascade, banks, carries, prev0,
                                  input_seq, ks)
             if last_lif:
@@ -1627,7 +1649,9 @@ class NetworkEngine:
                 l_tl = jax.lax.pmax(l_tl, axes)
                 ev_tl = jax.lax.psum(ev_tl, axes)
                 flush = jax.lax.psum(flush, axes)
-            return primary, out_seq, hidden, e_tl, l_tl, ev_tl, flush
+                if st_tl is not None:
+                    st_tl = jax.lax.pmax(st_tl.astype(jnp.int32), axes) > 0
+            return primary, out_seq, hidden, e_tl, l_tl, ev_tl, flush, st_tl
 
         if not sharded:
             return jax.jit(sim)
@@ -1635,7 +1659,7 @@ class NetworkEngine:
         carry_specs, prev_specs, bspec2, seq_spec, hidden_spec, bank_specs \
             = self._shard_specs(b, banks)
         out_specs = (bspec2, seq_spec, hidden_spec,
-                     P_REPL, P_REPL, P_REPL, P_REPL)
+                     P_REPL, P_REPL, P_REPL, P_REPL, P_REPL)
         return shard_over_batch(
             sim, self.mesh,
             in_specs=(seq_spec, carry_specs, prev_specs, bank_specs),
@@ -1669,7 +1693,7 @@ class NetworkEngine:
             self._trace_count += 1
             t_steps = input_seq.shape[0]
             ks = k0 + jnp.arange(t_steps, dtype=jnp.float32)
-            (carries, prev_ys), (out_seq, hidden, e_tl, l_tl, ev_tl) = \
+            (carries, prev_ys), (out_seq, hidden, e_tl, l_tl, ev_tl, _) = \
                 self._scan_chunk(cascade, banks, carries, prev_ys,
                                  input_seq, ks)
             if last_lif:
@@ -1765,7 +1789,7 @@ class NetworkEngine:
                 carries, prev_ys = state
                 u_in, k = xs
                 live = k < end_ks
-                new_carries, new_ys, es, ls, evs = cascade(
+                new_carries, new_ys, es, ls, evs, _ = cascade(
                     banks, carries, prev_ys, u_in, k, packs, live=live)
                 out = (new_ys[-1],
                        tuple(new_ys) if record_hidden else (),
@@ -2023,7 +2047,7 @@ class NetworkEngine:
 
         with _span("execute", call=call):
             t0 = time.perf_counter()
-            primary, out_seq, hidden, e_tl, l_tl, ev_tl, flush = \
+            primary, out_seq, hidden, e_tl, l_tl, ev_tl, flush, st_tl = \
                 jax.block_until_ready(compiled(x, carries, prev0, banks))
             wall = time.perf_counter() - t0
         last_lif = spec.circuits[-1] == "lif"
@@ -2042,6 +2066,10 @@ class NetworkEngine:
                 clock_ns=self.clock_ns, wall_seconds=wall,
                 circuits=spec.circuits, compile_seconds=compile_s)
             fetched = [primary, e_tl, l_tl, ev_tl, flush, *hidden]
+            if st_tl is not None:       # the scan's flags; not the chunk
+                fetched.append(st_tl)   # kernel's, which emits none
+                span.set_metadata(
+                    idle_stage_ticks=int(np.sum(np.asarray(st_tl))))
             if last_lif:
                 fetched.append(out_seq)
             span.set_metadata(bytes=sum(a.nbytes for a in fetched))

@@ -132,8 +132,13 @@ def _predict_linear(a, x):
     return _linear_standardized(a, (x - a["mu"]) / a["sd"])
 
 
+# The bias comes first in each head's ``bias + dot``: XLA:CPU then fuses
+# the add into its dot wherever the head runs, rather than by the order in
+# which it meets the add's operands, so a head rounds alike in programs
+# that evaluate it once (under a branch) and twice (sharing the bias).
+
 def _linear_standardized(a, xs):
-    return jnp.matmul(xs, a["w"][:-1], precision=F32_DOT) + a["w"][-1]
+    return a["w"][-1] + jnp.matmul(xs, a["w"][:-1], precision=F32_DOT)
 
 
 def _predict_table(a, x):
@@ -166,7 +171,7 @@ def _predict_mlp(a, x):
 def _mlp_standardized(a, h):
     n_layers = sum(1 for k in a if k.startswith("w"))
     for i in range(n_layers):
-        h = jnp.matmul(h, a[f"w{i}"], precision=F32_DOT) + a[f"b{i}"]
+        h = a[f"b{i}"] + jnp.matmul(h, a[f"w{i}"], precision=F32_DOT)
         if i < n_layers - 1:
             h = jax.nn.relu(h)
     return h[..., 0] * a["y_sd"][0] + a["y_mu"][0]

@@ -32,6 +32,10 @@ Public API
     ``fused=False`` keeps the original one-``predict``-per-head
     formulation (the benchmark A/B baseline; results agree within a few
     ULPs — see docs/architecture.md, "Inference hot path").
+:func:`stale_circuits`
+    Algorithm 1's line 4 as a mask: the circuits a tick catches up with
+    one merged idle event. The default standalone tick evaluates that
+    idle stage only when the mask holds a circuit.
 :class:`RowBlocks` / :func:`row_blocks_ok`
     rows that repeat blocks of columns (crossbar rows), handed to
     ``lasana_step`` in place of per-row inputs, at the blocks' own
@@ -111,6 +115,22 @@ def _derived(circ, x, params) -> dict:
     the function the augmentation applies to whole rows."""
     fn = getattr(circ, "surrogate_features", None)
     return {} if fn is None else {"derived": fn(x, params)}
+
+
+def stale_circuits(state: LasanaState, changed, t, clock_ns):
+    """Algorithm 1's line 4: the circuits with an event at ``t`` whose
+    last event was more than one clock ago, due one merged idle catch-up
+    event (lines 5-6)."""
+    return changed & (state.t_last < t - clock_ns)
+
+
+def _idle_when_stale(stale, idle_eval):
+    """Algorithm 1's lines 3-9, ``idle_eval() -> (e_s_idle, v_hat)``,
+    evaluated only on a tick where some circuit is stale. The skip branch
+    returns zeros, which is exact: ``_finish_tick`` reads both results
+    only where ``stale``."""
+    zeros = jnp.zeros(stale.shape, jnp.float32)
+    return jax.lax.cond(jnp.any(stale), idle_eval, lambda: (zeros, zeros))
 
 
 def init_state(n: int, params) -> LasanaState:
@@ -251,7 +271,7 @@ def _lasana_step_fused(surrogate, state, changed, x, t, clock_ns, *,
     three fused dispatches per tick:
 
       1. idle variant: M_ES + M_V stacked (the v' catch-up feeds the
-         active features)
+         active features), on a tick where some circuit is stale only
       2. active variant: M_O + M_V + M_ES stacked (only M_O's resolved
          output is needed downstream, but M_V/M_ES don't depend on it —
          so the whole variant is one pass)
@@ -268,16 +288,20 @@ def _lasana_step_fused(surrogate, state, changed, x, t, clock_ns, *,
 
     # --- lines 3-9: catch up stale circuits with one merged idle event
     with jax.named_scope("features"):
-        stale = changed & (state.t_last < t - clock_ns)
+        stale = stale_circuits(state, changed, t, clock_ns)
         tau_idle = jnp.maximum(t - state.t_last - clock_ns, 0.0)
-        aug_idle = _augment(circuit, _features(jnp.zeros_like(x), state.v,
-                                               tau_idle, state.params))
         tau_act = jnp.full((n,), clock_ns, jnp.float32)
+
+    def idle_features():
+        with jax.named_scope("features"):
+            return _augment(circuit, _features(jnp.zeros_like(x), state.v,
+                                               tau_idle, state.params))
 
     if annotate:
         v_cur = state.v            # behavioral state: never stale
         v_new = v_cur              # caller overwrites with behavioral state
         o_hat = known_out
+        aug_idle = idle_features()
         with jax.named_scope("features"):
             feats = _features(x, v_cur, tau_act, state.params)
             out_changed, o_resolved = _resolve_output(
@@ -294,18 +318,21 @@ def _lasana_step_fused(surrogate, state, changed, x, t, clock_ns, *,
         e_s_idle = r["idle"]["M_ES"]
         e_s, e_d, lat = r["act"]["M_ES"], r["tr"]["M_ED"], r["tr"]["M_L"]
     else:
-        with jax.named_scope("heads"):
-            r1 = surrogate.predict_heads(feats_idle=aug_idle,
-                                         heads={"idle": ("M_ES", "M_V")},
-                                         augmented=True,
-                                         fused_kernel=fused_kernel)
-        e_s_idle = r1["idle"]["M_ES"]
+        def idle_eval():
+            aug_idle = idle_features()
+            with jax.named_scope("heads"):
+                r1 = surrogate.predict_heads(
+                    feats_idle=aug_idle, heads={"idle": ("M_ES", "M_V")},
+                    augmented=True, fused_kernel=fused_kernel)
+            return r1["idle"]["M_ES"], r1["idle"]["M_V"]
+
+        e_s_idle, v_hat = _idle_when_stale(stale, idle_eval)
 
         # --- lines 10-22: one stacked pass over the whole active variant
         # (M_O's prediction chains into the transition-aware heads, but
         # M_V/M_ES don't consume it — so they ride the same dispatch)
         with jax.named_scope("features"):
-            v_cur = jnp.where(stale, r1["idle"]["M_V"], state.v)
+            v_cur = jnp.where(stale, v_hat, state.v)
             feats = _features(x, v_cur, tau_act, state.params)
             aug_act = _augment(circuit, feats)
         with jax.named_scope("heads"):
@@ -334,8 +361,9 @@ def _lasana_step_fused(surrogate, state, changed, x, t, clock_ns, *,
 def _lasana_step_blocks(surrogate, state, changed, blocks, t, clock_ns, *,
                         out_eps, spiking, vdd):
     """Algorithm 1 on rows given as :class:`RowBlocks`: the fused
-    path's schedule (idle M_ES + M_V -> active M_O + M_V + M_ES ->
-    transition M_ED + M_L), each stage one ``predict_blocks`` dispatch.
+    path's schedule (idle M_ES + M_V when some row is stale -> active
+    M_O + M_V + M_ES -> transition M_ED + M_L), each stage one
+    ``predict_blocks`` dispatch.
 
     v, tau, o_prev and o_new are one-column blocks per row; inputs,
     parameters and derived columns keep the blocks' shapes. Every head
@@ -360,13 +388,18 @@ def _lasana_step_blocks(surrogate, state, changed, blocks, t, clock_ns, *,
 
     # --- lines 3-9: catch up stale circuits with one merged idle event
     with jax.named_scope("features"):
-        stale = changed & (state.t_last < t - clock_ns)
+        stale = stale_circuits(state, changed, t, clock_ns)
         tau_idle = jnp.maximum(t - state.t_last - clock_ns, 0.0)
-        idle = {"x": zero_x, "v": col(state.v), "tau": col(tau_idle),
-                "p": blocks.params,
-                **_derived(circ, zero_x, blocks.params)}
-    with jax.named_scope("heads"):
-        e_s_idle, v_hat = run("idle", idle)
+
+    def idle_eval():
+        with jax.named_scope("features"):
+            idle = {"x": zero_x, "v": col(state.v), "tau": col(tau_idle),
+                    "p": blocks.params,
+                    **_derived(circ, zero_x, blocks.params)}
+        with jax.named_scope("heads"):
+            return run("idle", idle)
+
+    e_s_idle, v_hat = _idle_when_stale(stale, idle_eval)
 
     # --- lines 10-22: the active rows, then the transition rows
     with jax.named_scope("features"):

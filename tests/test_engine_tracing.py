@@ -137,12 +137,86 @@ def test_byte_counters(traced):
     for ((_, _, _, _), inside), run, x in zip(calls, runs, stimuli):
         got = {ev[0]: ev[3].get("bytes") for ev in inside}
         assert got["lasana.stimulus"] == x.nbytes
+        # the records, and one idle-stage flag a (tick, layer)
         records = [run.outputs, run.energy, run.latency,
                    run.events.astype(np.int32), run.flush_energy,
-                   *run.layer_spikes]
+                   run.events.astype(bool), *run.layer_spikes]
         if run.out_spikes is not None:
             records.append(run.out_spikes)
         assert got["lasana.fetch"] == sum(a.nbytes for a in records)
+
+
+def _stale_pairs(spec, x, run) -> int:
+    """(tick, layer) pairs on which some circuit of a LIF chain is stale:
+    it has an event, and its last event is more than one tick back (none
+    yet reads as tick -1). Events from the stimulus and the recorded
+    spikes, which are 0 or the spike amplitude."""
+    pre, pairs = np.asarray(x) > 0, 0
+    for layer, spikes in zip(spec.layers, run.layer_spikes):
+        changed = (pre.astype(np.float32)
+                   @ (np.abs(layer.weight) > 0).astype(np.float32)) > 0
+        last = np.full(changed.shape[1:], -1)
+        for k, c in enumerate(changed):
+            pairs += bool(np.any(c & (last < k - 1)))
+            last[c] = k
+        pre = spikes > 0
+    return pairs
+
+
+@pytest.fixture(scope="module")
+def idle_traced(nets, tmp_path_factory):
+    """The LIF chain under two stimuli: every input spiking on every tick,
+    and the module's sparse stimulus with silent ticks and lanes."""
+    lif, lsur, x_lif = nets["lif"]
+    sparse = x_lif.copy()
+    sparse[5] = 0.0
+    sparse[17, :2] = 0.0
+    stimuli = {"every_tick": np.full_like(x_lif, 1.5), "sparse": sparse}
+    log_dir = str(tmp_path_factory.mktemp("idle"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        runs = {k: lasana.simulate(lif, x, surrogates=lsur)
+                for k, x in stimuli.items()}
+    finally:
+        jax.profiler.stop_trace()
+    stats = [ev[3] for ev in _events(log_dir) if ev[0] == "lasana.fetch"]
+    return lif, stimuli, runs, dict(zip(stimuli, stats))
+
+
+@pytest.mark.parametrize("stimulus", ["every_tick", "sparse"])
+def test_idle_stage_ticks_counts_stale_pairs(idle_traced, stimulus):
+    spec, stimuli, runs, stats = idle_traced
+    want = _stale_pairs(spec, stimuli[stimulus], runs[stimulus])
+    assert stats[stimulus]["idle_stage_ticks"] == want
+    if stimulus == "every_tick":
+        assert want == 0
+    else:
+        assert want > 0
+
+
+def test_idle_stage_ticks_on_the_chunk_kernel_path(nets, idle_traced,
+                                                   tmp_path_factory):
+    """A single LIF layer runs the time-looped chunk kernel with the
+    fused-kernel switch on: that program emits no stale flags, so its
+    span carries no count, while the scan's span counts the stale pairs
+    and both records agree."""
+    spec, stimuli, _, _ = idle_traced
+    one = snn_spec([spec.layers[0].weight], [spec.layers[0].params])
+    sur = nets["lif"][1]
+    assert lasana.engine(one, fused_kernel=True)._chunk_eligible()
+    log_dir = str(tmp_path_factory.mktemp("chunk"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        runs = [lasana.simulate(one, stimuli["sparse"], surrogates=sur,
+                                fused_kernel=fk) for fk in (True, False)]
+    finally:
+        jax.profiler.stop_trace()
+    stats = [ev[3] for ev in _events(log_dir) if ev[0] == "lasana.fetch"]
+    want = _stale_pairs(one, stimuli["sparse"], runs[1])
+    assert want > 0
+    assert "idle_stage_ticks" not in stats[0]
+    assert stats[1]["idle_stage_ticks"] == want
+    np.testing.assert_array_equal(runs[0].events, runs[1].events)
 
 
 def test_wall_seconds_inside_execute(traced):

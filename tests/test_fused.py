@@ -27,7 +27,7 @@ from repro.core.circuits import LIFNeuron
 from repro.core.surrogate import (ALG1_HEADS, FORMAT_VERSION, Manifest,
                                   Surrogate, _augment)
 from repro.core.wrapper import (_features, _splice_transition, init_state,
-                                lasana_step)
+                                lasana_step, stale_circuits)
 
 # documented fused-vs-percall tolerance (see module docstring)
 RTOL = 1e-5
@@ -210,9 +210,28 @@ def test_predict_heads_misuse_raises():
         sur.predict_heads(feats_idle=fi, heads={"act": ("M_O",)})
 
 
+def _event_masks(stimulus: str, n: int, ticks: int = 5):
+    """(ticks, n) event masks: every circuit has an event on every tick
+    ("every_tick"), or circuits are eventless on chosen ticks ("sparse")
+    and go stale on their next event."""
+    masks = np.ones((ticks, n), bool)
+    if stimulus == "sparse":
+        masks[1, :n // 3] = False          # a third of the circuits idle
+        masks[3] = False                   # a silent tick
+    return masks
+
+
+# ticks on which a circuit is stale, from a state with stale circuits:
+# the fused tick runs its idle stage on these only
+IDLE_TICKS = {"every_tick": [True, False, False, False, False],
+              "sparse": [True, False, True, False, True]}
+
+
+@pytest.mark.parametrize("stimulus", list(IDLE_TICKS))
 @pytest.mark.parametrize("spiking", [True, False])
-def test_lasana_step_fused_matches_percall(spiking):
-    """One full Algorithm-1 tick: fused vs per-call within tolerance."""
+def test_lasana_step_fused_matches_percall(spiking, stimulus):
+    """Algorithm-1 ticks: fused vs per-call within tolerance, on ticks
+    that skip the idle stage (no circuit stale) and on ticks that run it."""
     sur = _make_surrogate(ALL_FAMILY_ASSIGNMENTS[0], seed=8)
     circ = LIFNeuron()
     key = jax.random.PRNGKey(11)
@@ -223,17 +242,52 @@ def test_lasana_step_fused_matches_percall(spiking):
         v=jax.random.uniform(k2, (n,), jnp.float32, 0.0, 1.2),
         t_last=jnp.asarray(np.random.default_rng(0)
                            .choice([0.0, 5.0, 15.0], n).astype(np.float32)))
-    changed = jax.random.bernoulli(k3, 0.7, (n,))
-    x = circ.sample_inputs(k3, (n,))
-    out_f = lasana_step(sur, state, changed, x, 20.0, 5.0, spiking=spiking,
-                        fused=True)
-    out_u = lasana_step(sur, state, changed, x, 20.0, 5.0, spiking=spiking,
-                        fused=False)
-    for a, b, name in zip(out_f, out_u, ("state", "e", "l", "o")):
-        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
-        for xa, xb in zip(la, lb):
-            np.testing.assert_allclose(np.asarray(xa), np.asarray(xb),
-                                       rtol=RTOL, atol=ATOL, err_msg=name)
+    ran = []
+    for k, changed in enumerate(jnp.asarray(_event_masks(stimulus, n))):
+        t = 20.0 + 5.0 * k
+        x = circ.sample_inputs(jax.random.fold_in(k3, k), (n,))
+        ran.append(bool(jnp.any(stale_circuits(state, changed, t, 5.0))))
+        out_f = lasana_step(sur, state, changed, x, t, 5.0,
+                            spiking=spiking, fused=True)
+        out_u = lasana_step(sur, state, changed, x, t, 5.0,
+                            spiking=spiking, fused=False)
+        for a, b, name in zip(out_f, out_u, ("state", "e", "l", "o")):
+            la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+            for xa, xb in zip(la, lb):
+                np.testing.assert_allclose(np.asarray(xa), np.asarray(xb),
+                                           rtol=RTOL, atol=ATOL,
+                                           err_msg=f"{name}, tick {k}")
+        state = out_u[0]
+    assert ran == IDLE_TICKS[stimulus]
+
+
+def test_idle_skip_is_exact():
+    """A tick on which no circuit is stale skips the idle heads; the same
+    tick with one more circuit stale runs them. The other circuits'
+    records and state are bit-identical between the two. The extra
+    circuit is there in both, fresh in the first, so that both runs
+    compile the same shapes (XLA:CPU's dots round apart at another
+    row count)."""
+    sur = _make_surrogate(ALL_FAMILY_ASSIGNMENTS[0], seed=8)
+    circ = LIFNeuron()
+    n = 24
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(17), 3)
+    fresh = init_state(n + 1, circ.sample_params(k1, n + 1))._replace(
+        v=jax.random.uniform(k2, (n + 1,), jnp.float32, 0.0, 1.2),
+        t_last=jnp.full((n + 1,), 15.0))
+    stale = fresh._replace(t_last=fresh.t_last.at[n].set(0.0))
+    changed = jnp.ones((n + 1,), bool)
+    x = circ.sample_inputs(k3, (n + 1,))
+    assert not bool(jnp.any(stale_circuits(fresh, changed, 20.0, 5.0)))
+    assert bool(stale_circuits(stale, changed, 20.0, 5.0)[n])
+    step = jax.jit(lambda st: lasana_step(
+        sur, st, changed, x, 20.0, 5.0, spiking=True, fused=True,
+        fused_kernel=False))
+    for name, a, b in zip(("v", "o", "t_last", "params", "e", "l", "o_out"),
+                          jax.tree.leaves(step(fresh)),
+                          jax.tree.leaves(step(stale))):
+        np.testing.assert_array_equal(np.asarray(a)[:n], np.asarray(b)[:n],
+                                      err_msg=name)
 
 
 def test_lasana_step_fused_annotation_single_dispatch_matches():

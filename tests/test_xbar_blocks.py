@@ -27,7 +27,7 @@ from repro.core.network import (NetworkEngine, _row_segments,
 from repro.core.surrogate import (FAMILY_PREDICT, FORMAT_VERSION,
                                   Manifest, Surrogate, _feature_names)
 from repro.core.wrapper import (LasanaState, RowBlocks, lasana_step,
-                                row_blocks_ok)
+                                row_blocks_ok, stale_circuits)
 from repro.kernels import ops
 
 RTOL = 1e-5
@@ -107,61 +107,122 @@ HEAD_SETS = {
 }
 
 
-def _layer_case(seed, b=3, fan_in=72, n_out=5):
-    """Row blocks of one crossbar layer, the same rows broadcast per row,
-    and a state with stale and fresh rows; lane 0's second segment and
-    lane 1's every segment are dead (all-zero inputs)."""
+def _layer(seed, b=3, fan_in=72, n_out=5):
+    """One crossbar layer's rows (n_out, n_seg, K + 1) with live bias
+    rows, and a state of its rows over ``b`` lanes with stale and fresh
+    rows."""
     rng = np.random.default_rng(seed)
     w = rng.integers(-1, 2, (fan_in, n_out)).astype(np.float32)
     n_seg = -(-fan_in // K)
     rows = _row_segments(w, K).reshape(n_out, n_seg, K + 1)
     rows[..., K] = rng.integers(-1, 2, (n_out, n_seg))     # live bias rows
-    x = rng.uniform(-0.8, 0.8, (b, n_seg * K)).astype(np.float32)
-    x[:, fan_in:] = 0.0
-    x[0, K:2 * K] = 0.0
-    x[1] = 0.0
-    x_seg = x.reshape(b, n_seg, K)
-    xin = np.broadcast_to(x_seg[:, None], (b, n_out, n_seg, K)
-                          ).reshape(-1, K)
     pall = np.broadcast_to(rows.reshape(1, -1, K + 1),
                            (b, n_out * n_seg, K + 1)).reshape(-1, K + 1)
-    n = len(xin)
+    n = len(pall)
     state = LasanaState(
         v=jnp.asarray(rng.uniform(-2, 2, n), jnp.float32),
         o=jnp.asarray(rng.uniform(-2, 2, n), jnp.float32),
         t_last=jnp.asarray(rng.choice([0.0, 8.0, 16.0], n), jnp.float32),
         params=jnp.asarray(pall))
+    return rows, state
+
+
+def _stimulus(kind, seed, ticks=4, b=3, fan_in=72):
+    """(ticks, b, n_seg * K) input volts: every segment of every lane live
+    on every tick ("every_tick"), or dead segments and lanes on chosen
+    ticks ("sparse"): lane 0's second segment and lane 1 on tick 0, lane 2
+    on tick 2."""
+    rng = np.random.default_rng(seed)
+    n_seg = -(-fan_in // K)
+    x = rng.uniform(-0.8, 0.8, (ticks, b, n_seg * K)).astype(np.float32)
+    x[..., fan_in:] = 0.0
+    if kind == "sparse":
+        x[0, 0, K:2 * K] = 0.0
+        x[0, 1] = 0.0
+        x[2, 2] = 0.0
+    return x
+
+
+def _tick(rows, x):
+    """One tick's inputs ``x (b, n_seg * K)`` to the rows: the event mask,
+    the inputs broadcast per row, and the rows as blocks."""
+    n_out, n_seg = rows.shape[:2]
+    x_seg = x.reshape(len(x), n_seg, K)
+    xin = np.broadcast_to(x_seg[:, None], (len(x), n_out, n_seg, K)
+                          ).reshape(-1, K)
     changed = jnp.asarray(np.any(np.abs(xin) > 1e-6, axis=-1))
     blocks = RowBlocks(x=jnp.asarray(x_seg[:, None]),
                        params=jnp.asarray(rows[None]))
-    return state, changed, jnp.asarray(xin), blocks
+    return changed, jnp.asarray(xin), blocks
 
 
+# ticks on which a row is stale, from a state with stale rows: the block
+# step runs its idle stage on these only
+IDLE_TICKS = {"every_tick": [True, False, False, False],
+              "sparse": [True, True, False, True]}
+
+
+@pytest.mark.parametrize("stimulus", list(IDLE_TICKS))
 @pytest.mark.parametrize("heads", list(HEAD_SETS), ids=list(HEAD_SETS))
-def test_block_step_matches_row_step(heads):
-    """One tick on one layer: the block step reproduces ``lasana_step``
-    on the broadcast rows (fused path) within the fused contract, idle
-    catch-up and dead segments included."""
+def test_block_step_matches_row_step(heads, stimulus):
+    """Ticks on one layer: the block step reproduces ``lasana_step`` on
+    the broadcast rows (fused path) within the fused contract, dead
+    segments included, on ticks that skip the idle catch-up (no row
+    stale) and on ticks that run it."""
     sur = xbar_surrogate(HEAD_SETS[heads], seed=1)
     assert row_blocks_ok(sur)
-    state, changed, xin, blocks = _layer_case(2)
-    assert 0 < int(changed.sum()) < changed.size
-    assert bool(jnp.any(changed & (state.t_last < 20.0 - XB.clock_ns)))
-    step = jax.jit(lambda s, st, c, x: lasana_step(
-        s, st, c, x, 24.0, XB.clock_ns, fused=True, fused_kernel=False))
-    ref = step(sur, state, changed, xin)
-    got = step(sur, state, changed, blocks)
-    for name, r, g in (("e", ref[1], got[1]), ("l", ref[2], got[2]),
-                       ("o", ref[3], got[3]), ("v", ref[0].v, got[0].v),
-                       ("o_state", ref[0].o, got[0].o)):
-        assert_close(g, r, err_msg=name)
-    np.testing.assert_array_equal(np.asarray(got[0].t_last),
-                                  np.asarray(ref[0].t_last))
-    # rows without an event are untouched and charge nothing
-    idle = ~np.asarray(changed)
-    np.testing.assert_array_equal(np.asarray(got[1])[idle], 0.0)
-    np.testing.assert_array_equal(np.asarray(got[0].v)[idle],
-                                  np.asarray(state.v)[idle])
+    rows, state = _layer(2)
+    step = jax.jit(lambda s, st, c, x, t: lasana_step(
+        s, st, c, x, t, XB.clock_ns, fused=True, fused_kernel=False))
+    ran = []
+    for k, x in enumerate(_stimulus(stimulus, 3)):
+        t = 24.0 + XB.clock_ns * k
+        changed, xin, blocks = _tick(rows, x)
+        ran.append(bool(jnp.any(stale_circuits(state, changed, t,
+                                               XB.clock_ns))))
+        ref = step(sur, state, changed, xin, t)
+        got = step(sur, state, changed, blocks, t)
+        for name, r, g in (("e", ref[1], got[1]), ("l", ref[2], got[2]),
+                           ("o", ref[3], got[3]), ("v", ref[0].v, got[0].v),
+                           ("o_state", ref[0].o, got[0].o)):
+            assert_close(g, r, err_msg=f"{name}, tick {k}")
+        np.testing.assert_array_equal(np.asarray(got[0].t_last),
+                                      np.asarray(ref[0].t_last))
+        # rows without an event are untouched and charge nothing
+        idle = ~np.asarray(changed)
+        np.testing.assert_array_equal(np.asarray(got[1])[idle], 0.0)
+        np.testing.assert_array_equal(np.asarray(got[0].v)[idle],
+                                      np.asarray(state.v)[idle])
+        state = ref[0]
+    assert ran == IDLE_TICKS[stimulus]
+
+
+def test_block_idle_skip_is_exact():
+    """A block tick on which no row is stale skips the idle heads; the
+    same tick with a fourth lane of stale rows runs them. The first three
+    lanes' records and state are bit-identical between the two. The
+    fourth lane is there in both, fresh in the first, so that both runs
+    compile the same shapes (XLA:CPU's dots round apart at another
+    row count)."""
+    sur = xbar_surrogate(HEAD_SETS["stacked-mlp"], seed=1)
+    rows, state = _layer(2, b=4)
+    changed, _, blocks = _tick(rows, _stimulus("every_tick", 3, ticks=1,
+                                               b=4)[0])
+    n = 3 * rows.shape[0] * rows.shape[1]            # rows of lanes 0-2
+    fresh = state._replace(t_last=jnp.full_like(state.t_last,
+                                                24.0 - XB.clock_ns))
+    stale = fresh._replace(t_last=fresh.t_last.at[n:].set(0.0))
+    assert not bool(jnp.any(stale_circuits(fresh, changed, 24.0,
+                                           XB.clock_ns)))
+    assert bool(jnp.all(stale_circuits(stale, changed, 24.0,
+                                       XB.clock_ns)[n:]))
+    step = jax.jit(lambda st: lasana_step(sur, st, changed, blocks, 24.0,
+                                          XB.clock_ns))
+    for name, a, b in zip(("v", "o", "t_last", "params", "e", "l", "o_out"),
+                          jax.tree.leaves(step(fresh)),
+                          jax.tree.leaves(step(stale))):
+        np.testing.assert_array_equal(np.asarray(a)[:n], np.asarray(b)[:n],
+                                      err_msg=name)
 
 
 def test_predict_blocks_matches_predict_on_rows():
